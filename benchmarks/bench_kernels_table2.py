@@ -9,22 +9,10 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core import kernels_zoo
+from repro.kernels.wavefront import ops as wops
 from .common import batched_plan, emit, kernel_batch, timeit
 
 N, NQ, NR = 16, 128, 128
-
-
-def vmem_bytes(spec, n_pe=128, r=4096):
-    """Working set of the TPU kernel strip (see kernels/wavefront)."""
-    L = spec.n_layers
-    import jax.numpy as jnp
-    sb = jnp.dtype(spec.score_dtype).itemsize
-    cb = int(np.prod(spec.char_shape or (1,))) * \
-        jnp.dtype(spec.char_dtype).itemsize
-    return ((r + 1) * L * sb          # preserved row buffer
-            + 2 * n_pe * L * sb       # wavefront carries
-            + n_pe * cb + r * cb      # query strip + ref stream
-            + n_pe * (n_pe + r - 1))  # tb strip (uint8)
 
 
 def run(quick: bool = False):
@@ -40,7 +28,7 @@ def run(quick: bool = False):
         gcups = n * NQ * NR / sec / 1e9
         emit(f"table2/{kid:02d}_{name}", sec / n,
              f"aligns_per_s={aps:.0f} gcups={gcups:.3f} "
-             f"vmem_kib={vmem_bytes(spec) / 1024:.0f} "
+             f"vmem_kib={wops.vmem_bytes(spec, 4096, 4096, params) / 1024:.0f} "
              f"n_layers={spec.n_layers}")
 
 

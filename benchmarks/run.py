@@ -159,4 +159,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime import compile_cache
+    compile_cache.enable()
     main()

@@ -87,4 +87,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime import compile_cache
+    compile_cache.enable()
     sys.exit(main())

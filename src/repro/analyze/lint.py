@@ -18,6 +18,8 @@ import dataclasses
 import time
 from typing import Iterable, List, Optional, Sequence, Tuple
 
+from repro.kernels.wavefront import VMEM_CAP_BYTES
+
 from .context import PointContext
 from .findings import ERROR, Finding, Report
 from .hygiene import GLOBAL_RULES
@@ -31,7 +33,7 @@ RULES_BY_ID = {r.id: r for r in ALL_RULES}
 @dataclasses.dataclass
 class LintConfig:
     """Budgets and thresholds the R3xx/R4xx rules judge against."""
-    vmem_budget_bytes: int = 16 << 20     # per-core VMEM (TPU v4/v5 class)
+    vmem_budget_bytes: int = VMEM_CAP_BYTES   # most a Pallas kernel asks for
     tb_budget_bytes: int = 256 << 20      # per-block traceback store
     const_warn_bytes: int = 128 << 10     # captured-constant thresholds
     const_error_bytes: int = 16 << 20

@@ -299,7 +299,9 @@ def rule_x64_params(ctx, cfg) -> Iterator[Finding]:
 # ---------------------------------------------------------------------------
 # R3xx — transfer / sync lints
 # ---------------------------------------------------------------------------
-_CALLBACK_PRIMS = ("infeed", "outfeed")
+# host round-trips whose primitive name does not say "callback"
+# (``jax.debug.print`` traces to ``debug_print``)
+_HOST_PRIMS = frozenset({"debug_print", "infeed", "outfeed"})
 
 
 def rule_host_callback(ctx, cfg) -> Iterator[Finding]:
@@ -316,11 +318,10 @@ def rule_host_callback(ctx, cfg) -> Iterator[Finding]:
                       f"plan fails jaxpr tracing: {type(e).__name__}: {e}",
                       where)
         return
-    bad = sorted(p for p in prims
-                 if "callback" in p or p in _CALLBACK_PRIMS)
+    bad = sorted(p for p in prims if "callback" in p or p in _HOST_PRIMS)
     for p in bad:
         yield Finding("R301", ERROR,
-                      f"traced plan contains host round-trip primitive "
+                      f"traced plan contains host callback primitive "
                       f"{p!r} — every dispatch synchronizes device→host",
                       where)
 

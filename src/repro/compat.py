@@ -1,51 +1,15 @@
-"""Cross-version JAX compatibility shims.
+"""Mesh construction with the sharding mode the code base assumes.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to the ``jax``
-top level, and its replication-check kwarg was renamed ``check_rep`` ->
-``check_vma`` along the way.  Import it from here and pass either
-spelling; the shim translates to whatever the installed jax accepts.
+``jax.make_mesh`` is called with Auto axes throughout: every sharded
+program here relies on GSPMD propagation, not explicit-sharding types.
 """
 from __future__ import annotations
 
-import functools
-
-try:
-    from jax import shard_map as _shard_map          # jax >= 0.6
-    _CHECK_KW = "check_vma"
-except ImportError:                                  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f=None, **kw):
-    for alias in ("check_vma", "check_rep"):
-        if alias in kw and alias != _CHECK_KW:
-            kw[_CHECK_KW] = kw.pop(alias)
-    if f is None:
-        return functools.partial(shard_map, **kw)
-    return _shard_map(f, **kw)
+import jax
 
 
 def make_mesh(axis_shapes, axis_names, **kw):
-    """``jax.make_mesh`` across versions: older jax has neither the
-    ``axis_types`` kwarg nor ``jax.sharding.AxisType``; newer explicit-
-    sharding code wants Auto axes.  Extra kwargs are dropped when the
-    installed jax does not accept them."""
-    import jax
-
-    if "axis_types" not in kw and hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    try:
-        return jax.make_mesh(axis_shapes, axis_names, **kw)
-    except TypeError:
-        kw.pop("axis_types", None)
-        return jax.make_mesh(axis_shapes, axis_names, **kw)
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns a per-device list on older
-    jax and a flat dict on newer; normalize to a dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``jax.make_mesh`` with Auto axes unless ``axis_types`` is given."""
+    kw.setdefault("axis_types",
+                  (jax.sharding.AxisType.Auto,) * len(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names, **kw)

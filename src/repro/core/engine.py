@@ -16,19 +16,19 @@ This is the JAX analogue of the DP-HLS back-end (§5.1):
   * the reference sequence *streams* through the lane vector one position
     per wavefront, exactly like characters streaming through the systolic
     array (optimizations (c)/(d)),
-  * traceback pointers are emitted one contiguous row per wavefront and
-    the store is bit-packed ``tb_pack`` pointers per byte along the lane
-    axis (the address-coalesced traceback memory of §5.2 at the kernel's
-    declared ``ptr_bits`` width — a 4x cut in persistent tb memory for
-    2-bit FSMs),
+  * traceback pointers are emitted one contiguous row per wavefront,
+    bit-packed in the loop into int32 words of ``4 * tb_pack`` pointers
+    along the lane axis (the address-coalesced traceback memory of §5.2 at
+    the kernel's declared ``ptr_bits`` width — a 4x cut in tb memory for
+    2-bit FSMs, and a store the TPU keeps and gathers at 32 bits),
   * the masked running best + final reduction is §5.2's per-PE local max
     and reduction tree (corner-region kernels capture their single
     objective cell directly instead of reducing every wavefront).
 
 The user-facing surface is only ``spec.pe`` / ``spec.init_*`` — the engine
 body never changes per kernel (the paper's front-end/back-end separation).
-``strip=1, tb_pack=1, live_bound=Q+R`` reproduces the seed schedule bit
-for bit.
+``strip=1, tb_pack=1, live_bound=Q+R`` reproduces the seed schedule's
+alignments bit for bit.
 """
 from __future__ import annotations
 
@@ -39,7 +39,7 @@ import jax.numpy as jnp
 
 from . import types as T
 from .spec_utils import band_mask, region_mask
-from .traceback import pack_lanes
+from .traceback import pack_words
 
 
 # Per-backend default for anti-diagonals per loop step — the single
@@ -203,7 +203,7 @@ def run(spec: T.DPKernelSpec, params, query, ref, q_len=None, r_len=None,
             carry, tb_row = step(carry, d0 + k)
             if with_tb:
                 rows.append(tb_row)
-        return carry, (jnp.stack(rows) if with_tb else None)
+        return carry, (pack_words(jnp.stack(rows), pack) if with_tb else None)
 
     # d = 0 buffer: only lane 0 (cell (0,0)) is defined.
     buf_d0 = jnp.full((lanes, L), sent, dt)
@@ -226,7 +226,8 @@ def run(spec: T.DPKernelSpec, params, query, ref, q_len=None, r_len=None,
     live_steps = jnp.minimum(
         (jnp.asarray(live_bound, jnp.int32) + strip - 1) // strip,
         jnp.int32(n_steps))
-    tb0 = jnp.zeros((n_steps * strip, lanes), jnp.uint8) if with_tb else None
+    n_words = -(-lanes // (4 * pack))
+    tb0 = jnp.zeros((n_steps * strip, n_words), jnp.int32) if with_tb else None
 
     def cond(state):
         s = state[0]
@@ -256,13 +257,5 @@ def run(spec: T.DPKernelSpec, params, query, ref, q_len=None, r_len=None,
     _, final_carry, tb = jax.lax.while_loop(
         cond, wbody, (jnp.int32(0), carry0, tb0))
     best, bi, bj = final_carry[3], final_carry[4], final_carry[5]
-    layout = "diag" if pack == 1 else ("diag", pack)
-    if with_tb:
-        # one bulk packing pass over the whole store, not one per scan
-        # step: keeps the loop body lean (XLA:CPU codegen degrades with
-        # extra per-step ops) while the *persistent* artifact — what the
-        # serving path holds in flight per alignment — shrinks by pack.
-        # The Pallas kernel packs in-VMEM before its HBM store instead,
-        # which is where in-fill packing actually saves traffic.
-        tb = pack_lanes(tb, pack)
-    return T.DPResult(score=best, end_i=bi, end_j=bj, tb=tb, tb_layout=layout)
+    return T.DPResult(score=best, end_i=bi, end_j=bj, tb=tb,
+                      tb_layout=("diag", pack))

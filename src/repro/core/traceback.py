@@ -1,22 +1,24 @@
 """FSM traceback executor (paper §5.2, Listings 3/7).
 
-The matrix fill stores traceback pointers — packed ``pack`` per byte
-along the lane axis when the kernel declares a narrow ``ptr_bits`` —
-and traceback is a pointer chase driven by the kernel's FSM:
-``(state, ptr) -> (move, next_state)``.  ``run`` walks one alignment
-with a ``lax.while_loop``; ``run_batched`` walks a whole block with one
-loop over an active mask that exits as soon as every row has hit its
-stop cell (instead of paying the worst-case step count per row).
+The matrix fill stores traceback pointers packed into int32 words — a
+kernel that declares a narrow ``ptr_bits`` gets ``pack`` pointers per
+byte's worth of bits, ``4 * pack`` per word — and traceback is a pointer
+chase driven by the kernel's FSM: ``(state, ptr) -> (move, next_state)``.
+``run`` walks one alignment with a ``lax.while_loop``; ``run_batched``
+walks a whole block with one loop over an active mask that exits as soon
+as every row has hit its stop cell (instead of paying the worst-case
+step count per row).
 
 Pointer stores are layout-dependent:
-  * 'diag'  (wavefront engine):  tb[(i+j) - 1, i]   (coalesced, §5.2)
-  * 'row'   (reference engine):  tb[i, j]
-  * ('chunk', n_pe) (Pallas kernel): tb[chunk, lane, w], strip height
-    n_pe, lane = (i-1) % n_pe, chunk-local wavefront w = lane + j - 1.
-A lane-packed store appends the pack factor — ('diag', pack) /
-('chunk', n_pe, pack): ``pack`` pointers share one byte along the lane
-axis, each in a slot of 8 // pack bits (lane i lives in byte i // pack,
-slot i % pack).
+  * ('diag', pack) (wavefront engine): int32 words tb[(i+j) - 1, i % nw]
+    (coalesced, §5.2), lane i in slot i // nw of its word, where
+    ``nw = tb.shape[1]`` words cover the lane vector (:func:`pack_words`);
+  * 'row' (reference engine): tb[i, j];
+  * ('chunk', n_pe, pack) (Pallas kernel): int32 words
+    tb[chunk, w // per_word, lane], strip height n_pe, lane = (i-1) % n_pe,
+    chunk-local wavefront w = lane + j - 1, slot w % per_word.
+Every slot is 8 // pack bits wide; 32-bit words are what the TPU stores
+and gathers without widening the whole store.
 """
 from __future__ import annotations
 
@@ -32,68 +34,68 @@ class TracebackTruncated(RuntimeError):
     reaching a stop cell — the recorded path is a corrupt prefix."""
 
 
-def pack_lanes(ptr, pack: int):
-    """Pack pointers along the last axis: ``(..., lanes)`` small ints ->
-    ``(..., ceil(lanes / pack))`` uint8, ``pack`` slots of 8 // pack bits
-    per byte (slot s = lane ``base + s``).  ``pack=1`` is a cast."""
-    ptr = jnp.asarray(ptr)
-    if pack == 1:
-        return ptr.astype(jnp.uint8)
-    if pack not in (2, 4, 8):
+def word_layout(pack: int) -> tuple[int, int]:
+    """``(slot_bits, per_word)``: ``pack`` pointers per byte's worth of
+    bits, so ``4 * pack`` pointers per int32 word."""
+    if pack not in (1, 2, 4, 8):
         raise ValueError(f"pack must be 1, 2, 4 or 8, got {pack}")
     width = 8 // pack
+    return width, 32 // width
+
+
+def pack_words(ptr, pack: int):
+    """Pack pointers along the last axis into int32 words: ``(..., lanes)``
+    -> ``(..., nw)`` with ``nw = ceil(lanes / per_word)``; lane i lives in
+    word ``i % nw``, slot ``i // nw``.  Slots are contiguous lane slices,
+    so packing needs no lane-splitting reshape."""
+    width, per_word = word_layout(pack)
+    ptr = jnp.asarray(ptr).astype(jnp.int32) & ((1 << width) - 1)
     lanes = ptr.shape[-1]
-    padded = -(-lanes // pack) * pack
-    if padded != lanes:
+    nw = -(-lanes // per_word)
+    if nw * per_word != lanes:
         ptr = jnp.concatenate(
-            [ptr, jnp.zeros(ptr.shape[:-1] + (padded - lanes,), ptr.dtype)],
-            axis=-1)
-    slots = ptr.reshape(ptr.shape[:-1] + (padded // pack, pack))
-    slots = slots.astype(jnp.int32) & ((1 << width) - 1)
-    acc = jnp.zeros(slots.shape[:-1], jnp.int32)
-    for s in range(pack):
-        acc = acc | (slots[..., s] << (s * width))
-    return acc.astype(jnp.uint8)
+            [ptr, jnp.zeros(ptr.shape[:-1] + (nw * per_word - lanes,),
+                            jnp.int32)], axis=-1)
+    acc = ptr[..., :nw]
+    for s in range(1, per_word):
+        acc = acc | (ptr[..., s * nw:(s + 1) * nw] << (s * width))
+    return acc
 
 
-def _unpack(byte, slot, pack: int):
-    width = 8 // pack
-    return (byte >> (slot * width)).astype(jnp.int32) & ((1 << width) - 1)
+def word_slot(word, slot, pack: int):
+    """Pointer ``slot`` of an int32 word of a ``pack`` store."""
+    width, _ = word_layout(pack)
+    return (word >> (slot * width)) & ((1 << width) - 1)
 
 
 def _make_reader(tb, layout):
     """Return ``read(i, j) -> ptr`` for one pointer store layout."""
     if isinstance(layout, tuple) and layout[0] == "chunk":
-        n_pe = layout[1]
-        pack = layout[2] if len(layout) > 2 else 1
+        _, n_pe, pack = layout
+        _, per_word = word_layout(pack)
 
         def read(i, j):
             c = jnp.clip((i - 1) // n_pe, 0, tb.shape[0] - 1)
             lane = jnp.clip((i - 1) % n_pe, 0, n_pe - 1)
-            w = jnp.clip(lane + j - 1, 0, tb.shape[2] - 1)
-            byte = tb[c, lane // pack, w]
-            return _unpack(byte, lane % pack, pack)
+            w = jnp.clip(lane + j - 1, 0, tb.shape[1] * per_word - 1)
+            return word_slot(tb[c, w // per_word, lane], w % per_word, pack)
         return read
     if isinstance(layout, tuple) and layout[0] == "diag":
         pack = layout[1]
+        _, per_word = word_layout(pack)
+        nw = tb.shape[1]
 
         def read(i, j):
             d = jnp.clip(i + j - 1, 0, tb.shape[0] - 1)
-            byte = tb[d, jnp.clip(i // pack, 0, tb.shape[1] - 1)]
-            return _unpack(byte, i % pack, pack)
+            lane = jnp.clip(i, 0, nw * per_word - 1)
+            return word_slot(tb[d, lane % nw], lane // nw, pack)
         return read
-    if layout == "diag":
-        def read(i, j):
-            d = i + j - 1
-            d = jnp.clip(d, 0, tb.shape[0] - 1)
-            return tb[d, jnp.clip(i, 0, tb.shape[1] - 1)]
-    elif layout == "row":
+    if layout == "row":
         def read(i, j):
             return tb[jnp.clip(i, 0, tb.shape[0] - 1),
                       jnp.clip(j, 0, tb.shape[1] - 1)]
-    else:
-        raise ValueError(f"unknown tb layout {layout!r}")
-    return read
+        return read
+    raise ValueError(f"unknown tb layout {layout!r}")
 
 
 def default_max_len(tb_shape, layout) -> int:
@@ -101,9 +103,9 @@ def default_max_len(tb_shape, layout) -> int:
     shape: an upper bound on Q + R, plus one for the terminating cell —
     a walk can never legitimately exceed it."""
     if isinstance(layout, tuple) and layout[0] == "chunk":
-        n_pe = layout[1]
+        _, n_pe, pack = layout
         q = tb_shape[0] * n_pe
-        r = tb_shape[2] - n_pe + 1
+        r = tb_shape[1] * word_layout(pack)[1] - n_pe + 1
         return q + r + 1
     if layout == "row":
         return tb_shape[0] + tb_shape[1]

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 F32 = jnp.float32
 NEG_INF = -1e30
 
@@ -102,7 +100,7 @@ def flash_fill(q, k, v, *, causal: bool, window=None, blk: int = 512,
                         pltpu.VMEM((blk, 1), F32),
                         pltpu.VMEM((blk, 1), F32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )
     return fn(q, k, v)

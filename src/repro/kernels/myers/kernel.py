@@ -1,23 +1,26 @@
 """Pallas TPU kernel for the Myers bit-vector column sweep.
 
-Hardware mapping: one whole query column of DP cells is delta-encoded in
-``n_words`` 32-bit VP/VN words (TPU vector units carry no 64-bit ints),
-and the reference streams through a ``fori_loop`` one column per step —
-the systolic character stream of the wavefront kernel, except each
-"PE" here is a machine word covering 32 DP rows of bitwise ops.
+Hardware mapping: pairs are VPU lanes.  One whole query column of DP
+cells is delta-encoded in ``n_words`` 32-bit VP/VN words per pair (TPU
+vector units carry no 64-bit ints), held as ``(n_words, lanes)`` VMEM
+scratch, and the reference streams through a ``fori_loop`` one column
+per step — the systolic character stream of the wavefront kernel, except
+each "PE" is a machine word covering 32 DP rows of bitwise ops, and 128
+pairs advance together, one per lane.
 
 The word loop is unrolled in Python (``n_words`` is static and small:
-a 512-bucket is 16 words); words couple only through the scalar
-horizontal delta ``hin``/``hout``, so the unrolled chain is a short
-scalar recurrence over vector-register-resident words, not a carry
-chain.  The per-column Eq gather is hoisted to XLA (ops.py builds the
-``(R, n_words)`` column table), keeping the kernel free of dynamic
-2-D gathers.
+a 512-bucket is 16 words); words couple only through the horizontal
+delta ``hin``/``hout``, a lane vector, so the unrolled chain is a short
+recurrence over vector registers, not a carry chain.  The per-column Eq
+gather is hoisted to XLA (ops.py builds the ``(R, n_words, B)`` column
+table), keeping the kernel free of dynamic gathers.  The grid's second
+axis streams the table in column blocks, so the VMEM footprint does not
+grow with the reference bucket.
 
-The column loop runs to ``r_len`` (dynamic ``fori_loop`` bound — the
-bucket padding is never paid) but does not replicate the XLA engine's
-k-threshold early exit; ops.py applies the same k-saturation sentinel
-to the result, so the two variants agree bit for bit.
+Each lane stops at its own ``r_len`` (later columns leave its score
+alone) but the kernel does not replicate the XLA engine's k-threshold
+early exit; ops.py applies the same k-saturation sentinel to the result,
+so the two variants agree bit for bit.
 """
 from __future__ import annotations
 
@@ -28,15 +31,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 WORD_BITS = 32
+LANES = 128
 _WT = jnp.uint32
+# eq-table bytes streamed per grid step
+_BLOCK_BYTES = 2 << 20
 
 
-def _advance_scalar(hin, vp, vn, eq):
-    """One 32-bit word of one column (scalar variant of
-    ``core.myers._advance_word``)."""
+def _advance(hin, vp, vn, eq):
+    """One 32-bit word of one column for every lane (the lane-vector
+    form of ``core.myers._advance_word``)."""
     one = jnp.asarray(1, _WT)
     zero = jnp.asarray(0, _WT)
     hin_neg = jnp.where(hin < 0, one, zero)
@@ -56,78 +60,109 @@ def _advance_scalar(hin, vp, vn, eq):
     return hout, vp_out, vn_out, ph, mh
 
 
-def _kernel_body(glob, n_words, sent,
-                 lens_ref, eq_ref, score_ref, best_ref, bj_ref):
-    q_len = lens_ref[0]
-    r_len = lens_ref[1]
+def column_block(r_bucket: int, n_words: int) -> int:
+    """Columns per grid step: the largest power of two dividing the
+    bucket whose eq block stays within ``_BLOCK_BYTES``."""
+    per_col = -(-n_words // 8) * 8 * LANES * 4
+    rc = 1
+    while (rc * 2 <= r_bucket and r_bucket % (rc * 2) == 0
+           and rc * 2 * per_col <= _BLOCK_BYTES):
+        rc *= 2
+    return rc
+
+
+def _kernel_body(glob, n_words, sent, rc,
+                 lens_ref, eq_ref, score_ref, best_ref, bj_ref,
+                 vp_ref, vn_ref, acc_ref):
     wb = WORD_BITS
+    q_len = lens_ref[0:1, :]                      # (1, lanes)
+    r_len = lens_ref[1:2, :]
+    k = pl.program_id(1)
     sw = jnp.clip((q_len - 1) // wb, 0, n_words - 1)
-    sb = jnp.asarray(jnp.clip((q_len - 1) % wb, 0, wb - 1), _WT)
+    sb = jnp.clip((q_len - 1) % wb, 0, wb - 1).astype(_WT)
     hin0 = jnp.int32(1) if glob else jnp.int32(0)
     one = jnp.asarray(1, _WT)
 
-    def col(j, carry):
-        vp, vn, score, best, bj = carry
-        eq_col = pl.load(eq_ref, (pl.ds(j, 1), slice(None)))[0]  # (n_words,)
-        hin = hin0
-        new_vp, new_vn = [], []
-        inc = jnp.int32(0)
-        for w in range(n_words):           # static unroll; scalar hin chain
-            hout, vpo, vno, ph, mh = _advance_scalar(
-                hin, vp[w], vn[w], eq_col[w])
-            new_vp.append(vpo)
-            new_vn.append(vno)
+    @pl.when(k == 0)
+    def _():
+        vp_ref[...] = jnp.full(vp_ref.shape, ~jnp.asarray(0, _WT))
+        vn_ref[...] = jnp.zeros(vn_ref.shape, _WT)
+        acc_ref[0:1, :] = q_len
+        acc_ref[1:2, :] = jnp.full(q_len.shape, sent, jnp.int32)
+        acc_ref[2:3, :] = jnp.zeros(q_len.shape, jnp.int32)
+
+    def col(jj, carry):
+        score, best, bj = carry
+        j = k * rc + jj
+        eq_col = eq_ref[jj]                       # (n_words, lanes)
+        hin = jnp.broadcast_to(hin0, q_len.shape)
+        inc = jnp.zeros(q_len.shape, jnp.int32)
+        for w in range(n_words):                  # static unroll
+            hout, vpo, vno, ph, mh = _advance(
+                hin, vp_ref[w:w + 1, :], vn_ref[w:w + 1, :],
+                eq_col[w:w + 1, :])
+            vp_ref[w:w + 1, :] = vpo
+            vn_ref[w:w + 1, :] = vno
             d = ((ph >> sb) & one).astype(jnp.int32) - \
                 ((mh >> sb) & one).astype(jnp.int32)
             inc = jnp.where(sw == w, d, inc)
             hin = hout
-        vp = jnp.stack(new_vp)
-        vn = jnp.stack(new_vn)
-        score = score + inc
+        live = j < r_len
+        score = jnp.where(live, score + inc, score)
         if not glob:
-            upd = score < best             # strict: first argmin wins
+            upd = live & (score < best)           # strict: first argmin wins
             best = jnp.where(upd, score, best)
             bj = jnp.where(upd, j + 1, bj)
-        return vp, vn, score, best, bj
+        return score, best, bj
 
-    init = (~jnp.zeros((n_words,), _WT), jnp.zeros((n_words,), _WT),
-            q_len, jnp.int32(sent), jnp.int32(0))
-    _, _, score, best, bj = jax.lax.fori_loop(0, r_len, col, init)
-    score_ref[0] = score
-    best_ref[0] = best
-    bj_ref[0] = bj
+    carry = (acc_ref[0:1, :], acc_ref[1:2, :], acc_ref[2:3, :])
+    score, best, bj = jax.lax.fori_loop(0, rc, col, carry)
+    acc_ref[0:1, :] = score
+    acc_ref[1:2, :] = best
+    acc_ref[2:3, :] = bj
+
+    @pl.when(k == pl.num_programs(1) - 1)
+    def _():
+        score_ref[...] = score
+        best_ref[...] = best
+        bj_ref[...] = bj
 
 
 def myers_fill(eq_cols, lens, *, glob: bool, n_words: int, sent: int,
                interpret: bool = False):
-    """Launch the column sweep.
+    """Launch the column sweep over a batch of pairs.
 
-    ``eq_cols``: (R, n_words) uint32 per-column match words (ops.py
-    gathers ``peq[ref[j]]``); ``lens``: (2,) int32 ``[q_len, r_len]``.
-    Returns (score, best, bj), each (1,) int32 — corner score, last-row
+    ``eq_cols``: (B, R, n_words) uint32 per-column match words (ops.py
+    gathers ``peq[ref[j]]``); ``lens``: (B, 2) int32 ``[q_len, r_len]``.
+    Returns (score, best, bj), each (B,) int32 — corner score, last-row
     minimum and its first-argmin column.
     """
-    R = eq_cols.shape[0]
-    kernel = functools.partial(_kernel_body, glob, n_words, sent)
+    B, R = eq_cols.shape[0], eq_cols.shape[1]
+    bt = B if B <= LANES else LANES
+    Bp = -(-B // bt) * bt
+    eq = jnp.transpose(eq_cols.astype(_WT), (1, 2, 0))       # (R, nw, B)
+    lens = jnp.asarray(lens, jnp.int32).T                   # (2, B)
+    if Bp != B:
+        eq = jnp.pad(eq, ((0, 0), (0, 0), (0, Bp - B)))
+        lens = jnp.pad(lens, ((0, 0), (0, Bp - B)))
+    rc = column_block(R, n_words)
+    kernel = functools.partial(_kernel_body, glob, n_words, sent, rc)
+    lane_out = pl.BlockSpec((1, bt), lambda t, k: (0, t))
     fn = pl.pallas_call(
         kernel,
-        grid=(1,),
+        grid=(Bp // bt, R // rc),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),            # lens
-            pl.BlockSpec((R, n_words), lambda c: (0, 0)),     # eq_cols
+            pl.BlockSpec((2, bt), lambda t, k: (0, t)),              # lens
+            pl.BlockSpec((rc, n_words, bt), lambda t, k: (k, 0, t)),  # eq
         ],
-        out_specs=[
-            pl.BlockSpec((1,), lambda c: (0,)),
-            pl.BlockSpec((1,), lambda c: (0,)),
-            pl.BlockSpec((1,), lambda c: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
+        out_specs=[lane_out, lane_out, lane_out],
+        out_shape=[jax.ShapeDtypeStruct((1, Bp), jnp.int32)] * 3,
+        scratch_shapes=[pltpu.VMEM((n_words, bt), _WT),
+                        pltpu.VMEM((n_words, bt), _WT),
+                        pltpu.VMEM((3, bt), jnp.int32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="myers_fill",
     )
-    return fn(jnp.asarray(lens, jnp.int32), eq_cols)
+    return tuple(o[0, :B] for o in fn(lens, eq))

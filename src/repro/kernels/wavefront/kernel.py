@@ -1,25 +1,33 @@
 """Pallas TPU kernel for the DP matrix fill — the back-end §5.1/§5.2.
 
 Hardware mapping (FPGA -> TPU):
-  * the N_PE linear systolic array becomes the lane dimension of VPU vector
-    registers: one wavefront of N_PE cells is evaluated per inner-loop step;
-  * the chunked-rows schedule is the Pallas grid: grid step c processes the
-    strip of query rows [c*N_PE, (c+1)*N_PE); the TPU grid is sequential, so
-    the VMEM scratch ``row_buf`` carries the strip's bottom row to the next
-    strip — the paper's Preserved Row Score Buffer;
-  * the reference sequence streams through the lane vector one position per
-    wavefront (the systolic character stream);
-  * traceback pointers are written one lane-vector per wavefront at column
-    w — the address-coalesced TB memory (all PEs hit the same address in
+  * the N_PE linear systolic array is the 128-lane axis of a VPU vector:
+    every per-PE value is a ``(1, N_PE)`` lane vector, and one wavefront
+    of N_PE cells is evaluated per inner-loop step;
+  * the grid is ``(pair, strip)``: grid step ``(b, c)`` processes the
+    strip of query rows ``[c*N_PE, (c+1)*N_PE)`` of pair ``b``.  The strip
+    axis is sequential, so the VMEM scratch ``top`` carries a strip's
+    bottom row to the next strip — the paper's Preserved Row Score Buffer;
+  * the reference streams through the lanes one position per wavefront
+    (the systolic character stream).  The host skews it once per pair
+    (``r_skew[w, l] = ref[w - l]``), so each wavefront is one row load;
+  * neighbour scores move one PE down the array with a lane rotation
+    (``pltpu.roll``); lane 0 takes its ``up``/``diag`` inputs from the
+    rotated preserved-row buffer, whose row ``w`` holds, in its last lane,
+    the previous strip's bottom-row cell that lane 0 needs at wavefront
+    ``w - N_PE + 1``;
+  * traceback pointers of ``per_word`` consecutive wavefronts are packed
+    into one int32 word per lane and stored as one lane vector — the
+    address-coalesced TB memory (all PEs hit the same address in
     different banks);
   * per-lane running best + final host-side reduction is the per-PE local
     max and reduction tree of §5.2.
 
-VMEM budget (BlockSpec tiling): the strip's query block (N_PE), the full
-reference (R), boundary rows (R+1, L) and the two wavefront carries
-(N_PE, L) — for N_PE=128, R=4096, L=5, f32 this is ~260 KiB, far inside the
-~16 MiB VMEM of a TPU core; N_PE should be a multiple of the 128-lane VPU
-width on hardware (any value works in interpret mode).
+Pair lengths live in SMEM, as do the kernel's scalar parameters.  Every
+VMEM block is either a whole array or ends in ``(rows, N_PE)`` with the
+full extent of the array, which is what the TPU's (8, 128) tiling needs.
+N_PE should be a multiple of the 128-lane VPU width on hardware (any
+value >= 2 works in interpret mode).
 """
 from __future__ import annotations
 
@@ -30,181 +38,254 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 from repro.core.spec_utils import band_mask, region_mask
-from repro.core.traceback import pack_lanes
+from repro.core.traceback import word_layout
+
+def fill_geometry(n_pe: int, r_bucket: int, tb_pack: int) -> tuple[int, int]:
+    """``(n_groups, top_rows)``: packed tb words per lane, and rows of the
+    preserved-row buffer, for one strip over a reference bucket."""
+    _, per_word = word_layout(tb_pack)
+    n_groups = -(-(n_pe + r_bucket - 1) // per_word)
+    top_rows = -(-(n_groups * per_word + n_pe - 1) // 8) * 8
+    return n_groups, top_rows
 
 
-def _kernel_body(spec, n_pe, tb_pack, treedef, leaf_shapes,
-                 # refs (order must match ops.py):
-                 lens_ref, q_ref, r_ref, init_row_ref, init_col_ref,
+def _lane_pe(spec, n_pe):
+    """Evaluate ``spec.pe`` across the strip.  Scalar alphabets broadcast
+    the PE over ``(1, N_PE)`` lane vectors, which is what compiles for the
+    TPU; vector alphabets (profiles, complex signals) vmap it per lane."""
+    L = spec.n_layers
+
+    def lanes(params, q, r, diag, up, left, i, j):
+        if spec.char_shape == ():
+            scores, ptr = spec.pe(params, q, r, jnp.stack(diag),
+                                  jnp.stack(up), jnp.stack(left), i, j)
+            scores = jnp.asarray(scores).reshape(L, 1, n_pe)
+            return ([scores[k] for k in range(L)],
+                    jnp.broadcast_to(jnp.asarray(ptr), (1, n_pe)))
+        cd = spec.char_shape
+        col = lambda vs: jnp.concatenate(vs, axis=0).T    # (N_PE, L)
+        scores, ptr = jax.vmap(spec.pe, in_axes=(None,) + (0,) * 7)(
+            params, q.T.reshape((n_pe,) + cd), r.T.reshape((n_pe,) + cd),
+            col(diag), col(up), col(left), i[0], j[0])
+        scores = scores.reshape(n_pe, L).T
+        return ([scores[k][None] for k in range(L)],
+                jnp.broadcast_to(ptr, (n_pe,))[None])
+    return lanes
+
+
+def _kernel_body(spec, n_pe, tb_pack, n_groups, treedef, smem_leaf,
+                 leaf_shapes, lens_ref, q_ref, r_ref, row0_ref, colb_ref,
                  *rest):
     n_params = len(leaf_shapes)
     param_refs = rest[:n_params]
-    tb_ref, best_ref, bestj_ref = rest[n_params:n_params + 3]
-    row_buf = rest[n_params + 3]
+    outs = rest[n_params:-1]
+    top = rest[-1]
+    with_tb = spec.traceback is not None
+    if with_tb:
+        tb_ref, best_ref, bestj_ref = outs
+    else:
+        best_ref, bestj_ref = outs
 
     L = spec.n_layers
     dt = spec.score_dtype
     sent = spec.sentinel()
-    R = r_ref.shape[0]
-    cd = spec.char_shape
+    width, per_word = word_layout(tb_pack)
+    slot_mask = (1 << width) - 1
 
     leaves = []
-    for ref, shp in zip(param_refs, leaf_shapes):
-        v = ref[...]
-        leaves.append(v.reshape(shp) if shp != v.shape else v)
+    for ref, in_smem, shp in zip(param_refs, smem_leaf, leaf_shapes):
+        leaves.append(ref[0] if in_smem else ref[...].reshape(shp))
     params = jax.tree.unflatten(treedef, leaves)
 
-    c = pl.program_id(0)
-    q_len = lens_ref[0]
-    r_len = lens_ref[1]
+    b = pl.program_id(0)
+    c = pl.program_id(1)
+    q_len = lens_ref[2 * b]
+    r_len = lens_ref[2 * b + 1]
 
-    # --- strip setup -------------------------------------------------------
     @pl.when(c == 0)
     def _():
-        row_buf[...] = init_row_ref[...]
+        top[...] = row0_ref[...]
 
-    @pl.when(c > 0)
-    def _():
-        # top-left boundary of this strip = init column at global row c*N_PE
-        row_buf[0, :] = pl.load(init_col_ref, (pl.ds(c * n_pe, 1), slice(None)))[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n_pe), 1)
+    i_glob = c * n_pe + lane + 1              # global DP row per lane
+    q_lanes = q_ref[...]                      # (C, N_PE)
+    colb = [colb_ref[k:k + 1, :] for k in range(L)]
+    colb_down = [pltpu.roll(v, 1, 1) for v in colb]
+    pe = _lane_pe(spec, n_pe)
+    head = lane == 0
 
-    col_b = pl.load(init_col_ref, (pl.ds(c * n_pe + 1, n_pe), slice(None)))  # (N_PE, L)
-    q_chunk = q_ref[...]                                                      # (N_PE, *cd)
+    def top_row(row):
+        return [pltpu.roll(top[k, pl.ds(row, 1), :], 1, 1) for k in range(L)]
 
-    l_idx = jax.lax.iota(jnp.int32, n_pe)
-    i_glob = c * n_pe + l_idx + 1            # global DP row per lane
-    vpe = jax.vmap(spec.pe, in_axes=(None, 0, 0, 0, 0, 0, 0, 0))
+    def wavefront(w, s, carry):
+        prev2, prev, top_diag, acc, best_v, bestj_v = carry
+        j = w - lane + 1                      # column per lane
+        on_col0 = lane == w                   # lanes with j == 1
+        r_lanes = (r_ref[0, pl.ds(w, 1), :] if q_lanes.shape[0] == 1 else
+                   r_ref[:, pl.ds(w, 1), :].reshape(-1, n_pe))
+        top_up = top_row(w + n_pe - 1)
+        up = [jnp.where(head, t, pltpu.roll(p, 1, 1))
+              for t, p in zip(top_up, prev)]
+        diag = [jnp.where(head, t, jnp.where(on_col0, cb,
+                                             pltpu.roll(p, 1, 1)))
+                for t, cb, p in zip(top_diag, colb_down, prev2)]
+        left = [jnp.where(on_col0, cb, p) for cb, p in zip(colb, prev)]
 
-    def shift_down(v, head):
-        return jnp.concatenate([head[None], v[:-1]], axis=0)
-
-    def wavefront(w, carry):
-        prev2, prev, r_stream, best_v, bestj_v = carry
-        j = w - l_idx + 1                    # column per lane
-        # systolic reference stream: lane 0 consumes ref[w]
-        new_char = pl.load(r_ref, (pl.ds(jnp.clip(w, 0, R - 1), 1),) +
-                           (slice(None),) * len(cd))[0]
-        r_stream = shift_down(r_stream, new_char)
-
-        row_w = pl.load(row_buf, (pl.ds(jnp.clip(w, 0, R), 1), slice(None)))[0]
-        row_w1 = pl.load(row_buf, (pl.ds(jnp.clip(w + 1, 0, R), 1), slice(None)))[0]
-        up_v = shift_down(prev, row_w1)
-        diag_v = shift_down(prev2, row_w)
-        left_v = prev
-        on_col0 = (l_idx == w)[:, None]      # lanes with j == 1
-        left_v = jnp.where(on_col0, col_b, left_v)
-        diag_v = jnp.where(on_col0, shift_down(col_b, row_w), diag_v)
-
-        scores, ptr = vpe(params, q_chunk, r_stream, diag_v, up_v, left_v,
-                          i_glob, j)
-        scores = scores.reshape(n_pe, L).astype(dt)
-        ptr = ptr.reshape(n_pe).astype(jnp.uint8)
-
+        scores, ptr = pe(params, q_lanes, r_lanes, diag, up, left, i_glob, j)
         valid = (j >= 1) & (j <= r_len) & (i_glob <= q_len) & \
             band_mask(spec, i_glob, j)
-        cur = jnp.where(valid[:, None], scores, sent)
+        cur = [jnp.where(valid, v.astype(dt), sent) for v in scores]
+        if with_tb:
+            bits = jnp.where(valid, ptr.astype(jnp.int32) & slot_mask, 0)
+            acc = acc | (bits << (s * width))
 
-        # coalesced TB store: one contiguous lane-vector per wavefront,
-        # bit-packed tb_pack pointers per byte along the lane axis
-        # (int indices must be pl.ds slices: older pallas interpret-mode
-        # discharge rules only accept Slice/array indices)
-        packed = pack_lanes(jnp.where(valid, ptr, jnp.uint8(0)), tb_pack)
-        pl.store(tb_ref, (pl.ds(0, 1), slice(None), pl.ds(w, 1)),
-                 packed[None, :, None])
-
-        # preserved-row buffer: the strip's last PE exports its row
-        j_last = w - (n_pe - 1) + 1
-
-        @pl.when((j_last >= 1) & (j_last <= R))
-        def _():
-            pl.store(row_buf, (pl.ds(jnp.clip(j_last, 0, R), 1), slice(None)),
-                     cur[n_pe - 1][None])
+        # preserved-row buffer: the strip's last PE exports column
+        # w - N_PE + 2 of its row; the next strip reads it at row w
+        for k in range(L):
+            top[k, pl.ds(w, 1), :] = cur[k]
 
         # per-PE local best over the objective region (§5.2); under a
         # sum semiring each lane ⊕-accumulates its region mass instead
         # (sentinel candidates underflow to no-ops) and the host-side
         # reduction logsumexps the lanes
         rmask = region_mask(spec, i_glob, j, q_len, r_len)
-        cand = jnp.where(rmask, cur[:, spec.primary_layer], sent)
+        cand = jnp.where(rmask, cur[spec.primary_layer], sent)
         if spec.is_sum:
             best_v = spec.combine(best_v, cand)
         else:
             upd = spec.better(cand, best_v)
             best_v = jnp.where(upd, cand, best_v)
             bestj_v = jnp.where(upd, j, bestj_v)
-        return prev, cur, r_stream, best_v, bestj_v
+        return prev, cur, top_up, acc, best_v, bestj_v
 
-    init = (jnp.full((n_pe, L), sent, dt), jnp.full((n_pe, L), sent, dt),
-            jnp.zeros((n_pe,) + cd, spec.char_dtype),
-            jnp.full((n_pe,), sent, dt), jnp.zeros((n_pe,), jnp.int32))
-    carry = jax.lax.fori_loop(0, n_pe + R - 1, wavefront, init)
-    _, _, _, best_v, bestj_v = carry
-    best_ref[0, :] = best_v
-    bestj_ref[0, :] = bestj_v
+    def group(g, carry):
+        carry = carry[:3] + (jnp.zeros((1, n_pe), jnp.int32),) + carry[4:]
+        for s in range(per_word):             # unrolled: one tb word
+            carry = wavefront(g * per_word + s, s, carry)
+        if with_tb:
+            tb_ref[pl.ds(g, 1), :] = carry[3]
+        return carry
+
+    dead = [jnp.full((1, n_pe), sent, dt) for _ in range(L)]
+    init = (dead, dead, top_row(n_pe - 2), jnp.zeros((1, n_pe), jnp.int32),
+            jnp.full((1, n_pe), sent, dt), jnp.zeros((1, n_pe), jnp.int32))
+    carry = jax.lax.fori_loop(0, n_groups, group, init)
+    best_ref[...] = carry[4]
+    bestj_ref[...] = carry[5]
+    # the next strip's top-left corner is init_col at its first row - 1,
+    # which is the last lane of this strip's left boundary
+    for k in range(L):
+        top[k, pl.ds(n_pe - 2, 1), :] = colb[k]
 
 
-def wavefront_fill(spec, params, query, ref, lens, n_pe: int = 128,
+def _chars(x, n_char_dims):
+    """Flatten the char dims of ``(..., n, *char)`` into one trailing
+    axis ``C`` and widen to a 32-bit type: ``(..., n, C)``."""
+    lead = x.shape[:x.ndim - n_char_dims]
+    x = x.reshape(lead + (-1,))
+    wide = jnp.float32 if jnp.issubdtype(x.dtype, jnp.floating) else jnp.int32
+    return x.astype(wide)
+
+
+def wavefront_fill(spec, params, queries, refs, lens, n_pe: int = 128,
                    interpret: bool = False, tb_pack: int = 1):
-    """Launch the matrix-fill kernel.
+    """Launch the matrix-fill kernel over a batch of pairs.
 
-    query must be padded to a multiple of n_pe.  Returns (tb, best, best_j)
-    with best/best_j (C, N_PE) and tb (C, N_PE // tb_pack, N_PE+R-1) —
-    ``tb_pack`` pointers per byte along the lane axis.
+    ``queries`` ``(B, Q, *char)`` with Q a multiple of ``n_pe``; ``refs``
+    ``(B, R, *char)``; ``lens`` ``(B, 2)`` int32 ``[q_len, r_len]``.
+    Returns ``(tb, best, best_j)``: best/best_j ``(B, C, N_PE)`` and, for
+    kernels with a traceback, tb ``(B, C, n_groups, N_PE)`` int32 —
+    wavefront ``w`` of lane ``l`` in word ``w // per_word``, slot
+    ``w % per_word`` (see :func:`repro.core.traceback.word_layout`); tb is None otherwise.
     """
-    Q, R = query.shape[0], ref.shape[0]
-    assert Q % n_pe == 0
-    assert n_pe % tb_pack == 0, (n_pe, tb_pack)
-    n_lane_bytes = n_pe // tb_pack
+    B, Q, R = queries.shape[0], queries.shape[1], refs.shape[1]
+    if Q % n_pe:
+        raise ValueError(f"query bucket {Q} is not a multiple of n_pe={n_pe}")
     n_chunks = Q // n_pe
     L = spec.n_layers
     dt = spec.score_dtype
-    cd = spec.char_shape
-    wt = n_pe + R - 1
+    sent = spec.sentinel()
+    nc = len(spec.char_shape)
+    n_groups, top_rows = fill_geometry(n_pe, R, tb_pack)
+    _, per_word = word_layout(tb_pack)
+    W = n_groups * per_word
 
-    j_idx = jnp.arange(R + 1, dtype=jnp.int32)
-    i_idx = jnp.arange(Q + 1, dtype=jnp.int32)
-    init_row = jnp.asarray(spec.init_row(params, j_idx), dt).reshape(R + 1, L)
-    init_col = jnp.asarray(spec.init_col(params, i_idx), dt).reshape(Q + 1, L)
+    # query strip per (pair, strip): (B, n_chunks, C, N_PE)
+    q = _chars(queries, nc).reshape(B, n_chunks, n_pe, -1)
+    q = jnp.swapaxes(q, 2, 3)
+    C = q.shape[2]
+    # skewed reference stream: r_skew[b, :, w, l] = ref[b, w - l]
+    r = _chars(refs, nc)                                    # (B, R, C)
+    w_idx = jnp.arange(W, dtype=jnp.int32)[:, None]
+    l_idx = jnp.arange(n_pe, dtype=jnp.int32)[None, :]
+    src = w_idx - l_idx
+    inside = (src >= 0) & (src < R)
+    r_skew = jnp.take(r, jnp.clip(src, 0, R - 1), axis=1)   # (B, W, N, C)
+    r_skew = jnp.where(inside[None, :, :, None], r_skew, 0)
+    r_skew = jnp.transpose(r_skew, (0, 3, 1, 2))            # (B, C, W, N)
+
+    # preserved-row buffer seed: row t holds init_row[t - N_PE + 2]
+    t_idx = jnp.arange(top_rows, dtype=jnp.int32) - (n_pe - 2)
+    row0 = jnp.asarray(spec.init_row(params, jnp.clip(t_idx, 0, R)),
+                       dt).reshape(top_rows, L)
+    row0 = jnp.where(((t_idx >= 0) & (t_idx <= R))[:, None], row0, sent)
+    row0 = jnp.broadcast_to(row0.T[:, :, None], (L, top_rows, n_pe))
+    # per-strip left boundary: colb[c, k, l] = init_col[c*N_PE + l + 1]
+    i_idx = jnp.arange(1, Q + 1, dtype=jnp.int32)
+    colb = jnp.asarray(spec.init_col(params, i_idx), dt).reshape(
+        n_chunks, n_pe, L)
+    colb = jnp.swapaxes(colb, 1, 2)
 
     leaves, treedef = jax.tree.flatten(params)
-    leaf_shapes = tuple(l.shape for l in leaves)
-    leaves_in = [jnp.atleast_1d(jnp.asarray(l)) for l in leaves]
+    leaf_shapes = tuple(jnp.shape(l) for l in leaves)
+    smem_leaf = tuple(int(jnp.size(l)) == 1 for l in leaves)
+    leaves_in = [jnp.reshape(jnp.asarray(l), (1,)) if sm else
+                 jnp.atleast_1d(jnp.asarray(l))
+                 for l, sm in zip(leaves, smem_leaf)]
+    lens = jnp.asarray(lens, jnp.int32).reshape(-1)
 
-    zero_map = lambda nd: (lambda c: (0,) * nd)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),                       # lens
-        pl.BlockSpec((n_pe,) + cd, lambda c: (c,) + (0,) * len(cd)),  # q strip
-        pl.BlockSpec((R,) + cd, zero_map(1 + len(cd))),               # ref
-        pl.BlockSpec((R + 1, L), zero_map(2)),                        # init_row
-        pl.BlockSpec((Q + 1, L), zero_map(2)),                        # init_col
-    ] + [pl.BlockSpec(l.shape, zero_map(l.ndim)) for l in leaves_in]
+        smem,                                                    # lens
+        pl.BlockSpec((None, None, C, n_pe), lambda b, c: (b, c, 0, 0)),
+        pl.BlockSpec((None, C, W, n_pe), lambda b, c: (b, 0, 0, 0)),
+        pl.BlockSpec((L, top_rows, n_pe), lambda b, c: (0, 0, 0)),
+        pl.BlockSpec((None, L, n_pe), lambda b, c: (c, 0, 0)),
+    ] + [smem if sm else
+         pl.BlockSpec(l.shape, functools.partial(
+             lambda nd, b, c: (0,) * nd, l.ndim))
+         for l, sm in zip(leaves_in, smem_leaf)]
 
-    out_specs = [
-        pl.BlockSpec((1, n_lane_bytes, wt), lambda c: (c, 0, 0)),     # tb
-        pl.BlockSpec((1, n_pe), lambda c: (c, 0)),                    # best
-        pl.BlockSpec((1, n_pe), lambda c: (c, 0)),                    # best_j
-    ]
-    out_shapes = [
-        jax.ShapeDtypeStruct((n_chunks, n_lane_bytes, wt), jnp.uint8),
-        jax.ShapeDtypeStruct((n_chunks, n_pe), dt),
-        jax.ShapeDtypeStruct((n_chunks, n_pe), jnp.int32),
-    ]
+    lane_block = pl.BlockSpec((None, None, 1, n_pe),
+                              lambda b, c: (b, c, 0, 0))
+    out_specs = [lane_block, lane_block]
+    out_shapes = [jax.ShapeDtypeStruct((B, n_chunks, 1, n_pe), dt),
+                  jax.ShapeDtypeStruct((B, n_chunks, 1, n_pe), jnp.int32)]
+    if spec.traceback is not None:
+        out_specs.insert(0, pl.BlockSpec((None, None, n_groups, n_pe),
+                                         lambda b, c: (b, c, 0, 0)))
+        out_shapes.insert(0, jax.ShapeDtypeStruct(
+            (B, n_chunks, n_groups, n_pe), jnp.int32))
 
-    kernel = functools.partial(_kernel_body, spec, n_pe, tb_pack, treedef,
-                               leaf_shapes)
+    from . import ops
+    vmem = ops.vmem_bytes(spec, Q, R, params, n_pe=n_pe, tb_pack=tb_pack)
+    kernel = functools.partial(_kernel_body, spec, n_pe, tb_pack, n_groups,
+                               treedef, smem_leaf, leaf_shapes)
     fn = pl.pallas_call(
         kernel,
-        grid=(n_chunks,),
+        grid=(B, n_chunks),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        scratch_shapes=[pltpu.VMEM((R + 1, L), dt)],
+        scratch_shapes=[pltpu.VMEM((L, top_rows, n_pe), dt)],
         interpret=interpret,
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=ops.vmem_limit(vmem)),
+        name=f"dp_fill_{spec.name}",
     )
-    return fn(jnp.asarray(lens, jnp.int32), query, ref, init_row, init_col,
-              *leaves_in)
+    out = fn(lens, q, r_skew, row0, colb, *leaves_in)
+    best, best_j = (o.reshape(B, n_chunks, n_pe) for o in out[-2:])
+    tb = out[0] if spec.traceback is not None else None
+    return tb, best, best_j

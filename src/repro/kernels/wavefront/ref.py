@@ -3,8 +3,10 @@
 The kernel must produce, for a given DPKernelSpec:
   * per-(chunk, lane) running-best score and its column, over the spec's
     objective region, and
-  * the chunk-local coalesced traceback store tb[chunk, lane, w]
-    (lane = row within chunk, w = chunk-local wavefront = lane + j - 1).
+  * the chunk-local coalesced traceback store (lane = row within chunk,
+    w = chunk-local wavefront = lane + j - 1), ``per_word`` wavefronts
+    packed per int32 word: tb[chunk, w // per_word, lane] holds the
+    pointer in slot w % per_word (``traceback.word_layout``).
 
 This oracle derives all three from the reference engine's full matrix.
 """
@@ -15,9 +17,12 @@ import numpy as np
 
 from repro.core import reference
 from repro.core.spec_utils import region_mask
+from repro.core.traceback import word_layout
+from repro.kernels.wavefront.kernel import fill_geometry
 
 
-def run(spec, params, query, ref, q_len=None, r_len=None, n_pe: int = 8):
+def run(spec, params, query, ref, q_len=None, r_len=None, n_pe: int = 8,
+        tb_pack: int = 1):
     Q, R = query.shape[0], ref.shape[0]
     assert Q % n_pe == 0, "oracle expects padded query"
     q_len = Q if q_len is None else int(q_len)
@@ -26,9 +31,10 @@ def run(spec, params, query, ref, q_len=None, r_len=None, n_pe: int = 8):
     scores = np.asarray(scores)
     tb = np.asarray(tb)
     n_chunks = Q // n_pe
-    wt = n_pe + R - 1
+    width, per_word = word_layout(tb_pack)
+    n_groups, _ = fill_geometry(n_pe, R, tb_pack)
 
-    tb_out = np.zeros((n_chunks, n_pe, wt), np.uint8)
+    tb_out = np.zeros((n_chunks, n_groups, n_pe), np.int64)
     best = np.full((n_chunks, n_pe), float(np.asarray(spec.sentinel())))
     best_j = np.zeros((n_chunks, n_pe), np.int32)
     ii = np.arange(Q + 1)[:, None]
@@ -43,10 +49,14 @@ def run(spec, params, query, ref, q_len=None, r_len=None, n_pe: int = 8):
                 continue
             for j in range(1, R + 1):
                 w = l + j - 1
-                tb_out[c, l, w] = tb[i, j]
+                g, slot = divmod(w, per_word)
+                tb_out[c, g, l] |= (int(tb[i, j]) & ((1 << width) - 1)) \
+                    << (slot * width)
                 if rmask[i, j]:
                     v = prim[i, j]
                     if (v < best[c, l]) if spec.is_min else (v > best[c, l]):
                         best[c, l] = v
                         best_j[c, l] = j
+    # int32 words: reinterpret the unsigned packing's top bit as sign
+    tb_out = tb_out.astype(np.uint32).view(np.int32)
     return best.astype(np.asarray(scores).dtype), best_j, tb_out

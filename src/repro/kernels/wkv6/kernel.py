@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 F32 = jnp.float32
 
 
@@ -85,7 +83,7 @@ def wkv6_fill(r, k, v, lw, u, *, s_blk: int = 2048, chunk: int = 32,
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), F32),
         scratch_shapes=[pltpu.VMEM((hd, hd), F32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )
     return fn(r, k, v, lw, u)

@@ -57,8 +57,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         ma = compiled.memory_analysis()
-        from repro.compat import cost_analysis_dict
-        ca = cost_analysis_dict(compiled)
+        ca = dict(compiled.cost_analysis() or {})
         hlo_text = compiled.as_text()
         if save_hlo:
             with open(save_hlo, "w") as f:

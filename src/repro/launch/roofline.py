@@ -22,20 +22,42 @@ from typing import Dict
 
 from repro.configs import ModelConfig, ShapeSpec
 
+# Published peaks of one TPU v5e chip (Google Cloud documentation,
+# "TPU v5e"): 197 TFLOP/s in bf16, 819 GB/s of HBM bandwidth.
 PEAK_FLOPS = 197e12          # bf16 / chip
 HBM_BW = 819e9               # bytes/s / chip
 LINK_BW = 50e9               # bytes/s / link (ICI)
 
-# Coarse per-backend (peak elementwise flops/s, memory bandwidth) pairs
-# for the plan autotuner's pre-timing ranking.  Absolute numbers are
-# deliberately rough — candidates at one sweep point share kernel,
-# bucket and batch, so only the *relative* compute/memory balance
-# matters for pruning; winners are still picked by measurement.
-BACKEND_PEAKS = {
-    "tpu": (PEAK_FLOPS, HBM_BW),
-    "gpu": (60e12, 2000e9),
+# (peak flops/s, memory bytes/s) per device, keyed by what JAX reports:
+# ``device_kind`` for accelerators, the platform name for the host CPU.
+# The plan autotuner ranks schedule candidates with these before timing.
+# A device that is not listed is an error, not a default.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (PEAK_FLOPS, HBM_BW),
+    # coarse host pair for CPU-side ranking, not a published peak
     "cpu": (100e9, 30e9),
 }
+
+
+def device_peaks(device=None) -> tuple[float, float]:
+    """``(peak flops/s, bytes/s)`` of ``device`` (default: JAX's first
+    device).  CPU devices resolve by platform, accelerators by
+    ``device_kind``; an unlisted device raises."""
+    import jax
+
+    if device is None:
+        device = jax.devices()[0]
+    key = "cpu" if device.platform == "cpu" else device.device_kind
+    return _peaks(key)
+
+
+def _peaks(key: str) -> tuple[float, float]:
+    try:
+        return DEVICE_PEAKS[key]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device {key!r}; known: "
+            f"{sorted(DEVICE_PEAKS)}") from None
 
 _WIRE = {"all-gather": lambda g: (g - 1) / g,
          "reduce-scatter": lambda g: (g - 1) / g,
@@ -176,6 +198,9 @@ def plan_roofline(cost, cells: float, *, backend: str = None,
                   trips: float = 1.0) -> PlanRoofline:
     """Roofline terms for one plan candidate from a ``hlo_cost.Cost``.
 
+    ``backend`` names a :data:`DEVICE_PEAKS` entry (``"cpu"`` or a
+    ``device_kind``); ``None`` takes JAX's first device.
+
     ``cost`` usually comes from :func:`hlo_cost.analyze_plan` over
     *lowered* (un-compiled) HLO, where while-loop trip counts are not
     yet annotated — the caller passes the analytic ``trips`` of the
@@ -184,11 +209,7 @@ def plan_roofline(cost, cells: float, *, backend: str = None,
     (there are no dots), so the compute term uses ``flops +
     ewise_flops``.
     """
-    import jax
-
-    if backend is None:
-        backend = jax.default_backend()
-    peak, bw = BACKEND_PEAKS.get(backend, BACKEND_PEAKS["cpu"])
+    peak, bw = device_peaks() if backend is None else _peaks(backend)
     return PlanRoofline(
         compute_s=(cost.flops + cost.ewise_flops) * trips / peak,
         memory_s=cost.bytes * trips / bw,

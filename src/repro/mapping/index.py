@@ -43,12 +43,14 @@ def kmer_hashes(seq, k: int):
         raise ValueError(f"k={k} exceeds {MAX_KMER} (2-bit packing)")
     seq = jnp.asarray(seq, jnp.uint32)
     n = seq.shape[0] - k + 1
-    idx = jnp.arange(n)[:, None] + jnp.arange(k)[None, :]
-    codes = seq[idx]
-    shifts = (jnp.uint32(2) * (k - 1 - jnp.arange(k, dtype=jnp.uint32)))
-    packed = jnp.sum((codes & 3) << shifts[None, :], axis=1,
-                     dtype=jnp.uint32)
-    unambig = jnp.all(codes < 4, axis=1)
+    # k shifted slices, not an (n, k) gather: the TPU compiler takes
+    # minutes over a gather that size at a 1 Mb reference
+    packed = jnp.zeros((n,), jnp.uint32)
+    unambig = jnp.ones((n,), bool)
+    for t in range(k):
+        codes = seq[t:t + n]
+        packed = (packed << 2) | (codes & 3)
+        unambig = unambig & (codes < 4)
     return jnp.where(unambig, mix32(packed), jnp.uint32(AMBIG_HASH))
 
 
@@ -61,11 +63,15 @@ def minimizers(seq, k: int, w: int):
     """
     h = kmer_hashes(seq, k)
     n_win = h.shape[0] - w + 1
-    win = jnp.arange(n_win)[:, None] + jnp.arange(w)[None, :]
-    hw = h[win]                                   # (n_win, w)
-    arg = jnp.argmin(hw, axis=1)
-    pos = (jnp.arange(n_win) + arg).astype(jnp.int32)
-    val = jnp.take_along_axis(hw, arg[:, None], axis=1)[:, 0]
+    # running minimum over w shifted slices; strict < keeps the leftmost
+    val = h[:n_win]
+    arg = jnp.zeros((n_win,), jnp.int32)
+    for t in range(1, w):
+        cand = h[t:t + n_win]
+        upd = cand < val
+        val = jnp.where(upd, cand, val)
+        arg = jnp.where(upd, jnp.int32(t), arg)
+    pos = jnp.arange(n_win, dtype=jnp.int32) + arg
     return pos, val
 
 

@@ -133,8 +133,7 @@ def run_pairs(spec, params, pairs: Sequence[tuple], *,
             rs[row, : rl[row]] = r
         plan = plan_mod.get_plan(spec, engine_name, (bq,) + char,
                                  (br,) + char, batch_size=block,
-                                 with_traceback=with_traceback, mode=mode,
-                                 donate=True)
+                                 with_traceback=with_traceback, mode=mode)
         return plan(params, jnp.asarray(qs), jnp.asarray(rs),
                     jnp.asarray(ql), jnp.asarray(rl))
 

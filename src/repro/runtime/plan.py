@@ -22,6 +22,7 @@ import numpy as np
 import repro.core.types as T
 import repro.core.traceback as tb_mod
 
+from repro.kernels.wavefront import N_PE as PALLAS_N_PE  # no Pallas import
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -152,8 +153,7 @@ class CompiledPlan:
     """
 
     def __init__(self, key: PlanKey, spec: T.DPKernelSpec,
-                 engine_name: str, donate: bool = False,
-                 mesh=None, mesh_axis: str = "data"):
+                 engine_name: str, mesh=None, mesh_axis: str = "data"):
         self.key = key
         self.spec = spec
         self.calls = 0
@@ -161,14 +161,10 @@ class CompiledPlan:
         self.compile_s = None  # trace+compile wall time of the first call
         fn = _build_fn(key, spec, engine_name)
 
-        # Buffer donation is only safe when the caller hands over freshly
-        # padded copies (the bucketed batch paths do); XLA:CPU does not
-        # implement donation, so gate on backend to avoid warnings.
-        donate_argnums = ()
-        if donate and jax.default_backend() != "cpu":
-            donate_argnums = (1, 2)
+        # no input buffer is donated: no output has the shape and dtype
+        # of the padded uint8 sequences, so XLA could never reuse one
         if mesh is None:
-            self._fn = jax.jit(fn, donate_argnums=donate_argnums)
+            self._fn = jax.jit(fn)
         else:
             # sharded plan: batch axis over ``mesh_axis``, params replicated
             # (the former private jit of core.batch.make_sharded_aligner,
@@ -180,7 +176,7 @@ class CompiledPlan:
             repl = NamedSharding(mesh, P())
             self._fn = jax.jit(
                 fn, in_shardings=(repl, bsh, bsh, bsh, bsh),
-                out_shardings=bsh, donate_argnums=donate_argnums)
+                out_shardings=bsh)
 
     @property
     def batch_size(self):
@@ -409,11 +405,6 @@ def lower_plan_hlo(spec: T.DPKernelSpec, params, engine_name: str,
     return lowered.compiler_ir(dialect="hlo").as_hlo_text()
 
 
-# lane-strip height of the Pallas kernel's ('chunk', n_pe) tb layout;
-# mirrors kernels.wavefront.ops.run's n_pe default (not imported here —
-# that would defeat the registry's lazy pallas loading)
-PALLAS_N_PE = 32
-
 
 def traceback_bytes(spec: T.DPKernelSpec, q_bucket: int, r_bucket: int, *,
                     engine_name: str = "wavefront",
@@ -425,9 +416,11 @@ def traceback_bytes(spec: T.DPKernelSpec, q_bucket: int, r_bucket: int, *,
     ``tb_pack``).
 
     Layout-aware per engine: the wavefront 'diag' store is
-    ⌈(Q+R)/strip⌉ * strip wavefront rows of ⌈(Q+1)/tb_pack⌉ bytes; the
-    Pallas ('chunk', n_pe) store is ⌈Q/n_pe⌉ chunks of (n_pe/tb_pack) *
-    (n_pe+R-1) bytes (Q padded up to the lane strip)."""
+    ⌈(Q+R)/strip⌉ * strip wavefront rows of ⌈(Q+1)/(4*tb_pack)⌉ int32
+    words; the
+    Pallas ('chunk', n_pe, tb_pack) store is ⌈Q/n_pe⌉ chunks of
+    ⌈(n_pe+R-1)/(4*tb_pack)⌉ int32 words per lane (Q padded up to the
+    lane strip)."""
     if spec.traceback is None:
         return 0
     r = resolve_engine_options(spec, engine_name,
@@ -436,16 +429,17 @@ def traceback_bytes(spec: T.DPKernelSpec, q_bucket: int, r_bucket: int, *,
     if engine_name.startswith("pallas"):
         n_pe = PALLAS_N_PE
         n_chunks = -(-q_bucket // n_pe)
-        return n_chunks * (n_pe // pack_r) * (n_pe + r_bucket - 1)
+        n_groups = -(-(n_pe + r_bucket - 1) // (4 * pack_r))
+        return n_chunks * n_groups * n_pe * 4
     n_rows = -(-(q_bucket + r_bucket) // strip_r) * strip_r
-    return n_rows * (-(-(q_bucket + 1) // pack_r))
+    return n_rows * (-(-(q_bucket + 1) // (4 * pack_r))) * 4
 
 
 def get_plan(spec: T.DPKernelSpec, engine_name: str,
              q_shape: tuple, r_shape: tuple, *,
              batch_size: Optional[int] = None,
              with_traceback: bool = True, mode: str = "align",
-             donate: bool = False, mesh=None,
+             mesh=None,
              mesh_axis: str = "data", strip: Optional[int] = None,
              tb_pack: Optional[int] = None,
              xdrop: Optional[int] = None) -> CompiledPlan:
@@ -483,12 +477,10 @@ def get_plan(spec: T.DPKernelSpec, engine_name: str,
             requested.update(tuned)
     opts = resolve_engine_options(spec, engine_name, requested)
     strip_r, pack_r, xdrop_r = opts["strip"], opts["tb_pack"], opts["xdrop"]
-    if jax.default_backend() == "cpu":
-        donate = False   # donation is a no-op on CPU; don't split the cache
     if mesh is None:
         mesh_axis = "data"   # axis is meaningless un-sharded; don't split
     cache_key = (spec, engine_name, tuple(q_shape), tuple(r_shape),
-                 batch_size, wtb, mode, donate, mesh, mesh_axis,
+                 batch_size, wtb, mode, mesh, mesh_axis,
                  strip_r, pack_r, xdrop_r)
     plan = _CACHE.get(cache_key)
     if plan is not None:
@@ -507,8 +499,8 @@ def get_plan(spec: T.DPKernelSpec, engine_name: str,
                           mode=mode, placement=_placement(mesh, mesh_axis),
                           strip=strip_r, tb_pack=pack_r,
                           semiring=spec.semiring.name, xdrop=xdrop_r)
-            plan = CompiledPlan(key, spec, engine_name, donate=donate,
-                                mesh=mesh, mesh_axis=mesh_axis)
+            plan = CompiledPlan(key, spec, engine_name, mesh=mesh,
+                                mesh_axis=mesh_axis)
             _CACHE[cache_key] = plan
         else:
             _STATS["hits"] += 1

@@ -144,8 +144,7 @@ class _AlignChannel(gateway_mod.Channel):
                 spec, svc.engine_name, qs.shape[1:], rs.shape[1:],
                 batch_size=block,
                 with_traceback=svc.with_traceback and
-                spec.traceback is not None,
-                donate=True)
+                spec.traceback is not None)
             out = plan(params, jnp.asarray(qs), jnp.asarray(rs),
                        jnp.asarray(ql), jnp.asarray(rl))
         return reqs, out
@@ -311,7 +310,7 @@ class AlignmentService(Gateway):
         grid exactly as a request of those lengths would be.
 
         Each entry warms the same plan ``_launch`` would resolve —
-        identical ``get_plan`` arguments, including donation and the
+        identical ``get_plan`` arguments, including the
         tuned-table default consultation — plus, on screenable channels,
         the prefilter's score-only screen plan.  Sharded channels
         (``mesh`` set) compile through ``core.batch`` lazily and are
@@ -345,7 +344,7 @@ class AlignmentService(Gateway):
                 spec, params, self.engine_name, q_shape, r_shape,
                 batch_size=block,
                 with_traceback=self.with_traceback and
-                spec.traceback is not None, donate=True)
+                spec.traceback is not None)
             n += 1
         return n
 

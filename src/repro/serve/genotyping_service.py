@@ -145,7 +145,7 @@ class _PairHMMChannel(gateway_mod.Channel):
             rs[i, : rl[i]] = job.ref
         plan = plan_mod.get_plan(svc.spec, svc.engine_name,
                                  (Lq,), (Lr,), batch_size=block,
-                                 with_traceback=False, donate=True)
+                                 with_traceback=False)
         out = plan(svc.params, jnp.asarray(qs), jnp.asarray(rs),
                    jnp.asarray(ql), jnp.asarray(rl))
         return jobs, out
@@ -247,7 +247,7 @@ class GenotypingService(Gateway):
             warm_mod.warm_plan(
                 self.spec, self.params, self.engine_name, (bucket[0],),
                 (bucket[1],), batch_size=self.block,
-                with_traceback=False, donate=True)
+                with_traceback=False)
         return len(entries)
 
     # -- intake ------------------------------------------------------------

@@ -149,14 +149,13 @@ class ReadMappingService(Gateway):
             spec, params = extend_mod.extension_spec(band, m.gap_mode)
             warm_mod.warm_plan(
                 spec, params, m.engine_name, (bucket[0],), (bucket[1],),
-                batch_size=m.block, with_traceback=True, donate=True)
+                batch_size=m.block, with_traceback=True)
             n += 1
             if m.filter_mode == "myers":
                 warm_mod.warm_plan(
                     extend_mod.SCREEN_SPEC, edit_kernel.default_params(1),
                     m.filter_engine, (bucket[0],), (bucket[1],),
-                    batch_size=m.screen_block, with_traceback=False,
-                    donate=True)
+                    batch_size=m.screen_block, with_traceback=False)
                 n += 1
         return n
 

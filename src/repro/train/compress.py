@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as Pspec
-from repro.compat import shard_map
+from jax import shard_map
 
 F32 = jnp.float32
 

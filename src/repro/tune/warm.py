@@ -48,7 +48,7 @@ def _dummy_args(spec, q_shape: tuple, r_shape: tuple,
 def warm_plan(spec, params, engine_name: str, q_shape: tuple,
               r_shape: tuple, *, batch_size: Optional[int] = None,
               with_traceback: bool = True, mode: str = "align",
-              donate: bool = False, **options) -> plan_mod.CompiledPlan:
+              **options) -> plan_mod.CompiledPlan:
     """Fetch the plan ``get_plan`` would serve for these arguments and
     force its compile with one dummy dispatch (no-op if already hot).
 
@@ -59,7 +59,7 @@ def warm_plan(spec, params, engine_name: str, q_shape: tuple,
     plan = plan_mod.get_plan(
         spec, engine_name, tuple(q_shape), tuple(r_shape),
         batch_size=batch_size, with_traceback=with_traceback, mode=mode,
-        donate=donate, **options)
+        **options)
     if plan.compile_s is None:
         out = plan(params, *_dummy_args(spec, q_shape, r_shape,
                                         batch_size))
@@ -68,8 +68,7 @@ def warm_plan(spec, params, engine_name: str, q_shape: tuple,
 
 
 def warm_grid(spec, params, engine_name: str, points, *,
-              with_traceback: bool = True, mode: str = "align",
-              donate: bool = False) -> int:
+              with_traceback: bool = True, mode: str = "align") -> int:
     """Warm one plan per ``(bucket, batch_size)`` point; returns the
     number of plans that actually compiled (already-hot points count 0).
     ``bucket`` is the per-pair length pair; char dims come from the
@@ -80,6 +79,6 @@ def warm_grid(spec, params, engine_name: str, points, *,
         plan = warm_plan(
             spec, params, engine_name, (bucket[0],) + char,
             (bucket[1],) + char, batch_size=batch_size,
-            with_traceback=with_traceback, mode=mode, donate=donate)
+            with_traceback=with_traceback, mode=mode)
         n += plan.hits == 0 and plan.calls <= 1
     return n

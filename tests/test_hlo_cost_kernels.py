@@ -161,3 +161,17 @@ class TestLoweredDialect:
         assert two.compute_s == pytest.approx(2 * one.compute_s)
         assert two.memory_s == pytest.approx(2 * one.memory_s)
         assert one.cells_per_s > two.cells_per_s
+
+    def test_roofline_unknown_device_raises(self, linear):
+        spec, params = linear
+        cost = hlo_cost.analyze_plan(spec, params, "wavefront",
+                                     (Q,), (R,), batch_size=2,
+                                     with_traceback=False, mode="fill")
+        cpu = roofline.plan_roofline(cost, Q * R * 2, backend="cpu")
+        assert cpu.cells_per_s > 0
+        v5e = roofline.plan_roofline(cost, Q * R * 2, backend="TPU v5 lite")
+        assert v5e.bound_s < cpu.bound_s
+        with pytest.raises(ValueError, match="no roofline peaks"):
+            roofline.plan_roofline(cost, Q * R * 2, backend="gpu")
+        with pytest.raises(ValueError, match="no roofline peaks"):
+            roofline.plan_roofline(cost, Q * R * 2, backend="TPU v9")

@@ -42,6 +42,35 @@ def test_minimizers_are_window_minima(rng):
         assert h[pos[t]] == val[t]
 
 
+def test_sketch_matches_numpy_packing_and_leftmost_minimum(rng):
+    k, w = 5, 4                   # short k: repeated hashes in windows
+    seq = alphabets.random_dna(rng, 400)
+    seq[100:103] = 4
+    n = len(seq) - k + 1
+    codes = np.stack([seq[t:t + n] for t in range(k)], axis=1).astype(np.int64)
+    packed = ((codes & 3) << (2 * np.arange(k - 1, -1, -1))).sum(axis=1)
+    want_h = np.where((codes < 4).all(axis=1),
+                      np.asarray(index_mod.mix32(packed.astype(np.uint32))),
+                      index_mod.AMBIG_HASH)
+    h = np.asarray(kmer_hashes(jnp.asarray(seq), k))
+    np.testing.assert_array_equal(h, want_h)
+    pos, val = minimizers(jnp.asarray(seq), k, w)
+    hw = np.stack([h[t:t + n - w + 1] for t in range(w)], axis=1)
+    np.testing.assert_array_equal(np.asarray(pos),
+                                  np.arange(len(hw)) + hw.argmin(axis=1))
+    np.testing.assert_array_equal(np.asarray(val), hw.min(axis=1))
+
+
+def test_sketch_lowers_without_gather():
+    """The index sketch is shifted slices: an (n, k) gather over a 1 Mb
+    reference takes the TPU compiler minutes."""
+    import jax
+
+    hlo = index_mod._sketch.lower(
+        jax.ShapeDtypeStruct((1 << 12,), jnp.uint8), 13, 8).as_text()
+    assert "gather" not in hlo
+
+
 def test_build_index_sorted_table_roundtrip(rng):
     ref = alphabets.random_dna(rng, 2000)
     idx = build_index(ref, k=13, w=8)

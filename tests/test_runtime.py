@@ -215,29 +215,48 @@ def test_pack_by_bucket_inverse_restores_order(block, rng):
 
 
 # ---------------------------------------------------------------------------
-# traceback-layout parity: the ('chunk', n_pe) reader must reproduce the
-# 'diag' and 'row' readers on identical pointer contents
+# traceback-layout parity: the ('chunk', n_pe, pack) and ('diag', pack)
+# word readers must reproduce the 'row' reader on identical pointers
 # ---------------------------------------------------------------------------
+def _chunk_words(row, Q, R, n_pe, pack):
+    """The Pallas ('chunk', n_pe, pack) store of a row-major pointer
+    matrix: wavefront w of lane l in word w // per_word, slot w % per_word."""
+    from repro.core.traceback import word_layout
+    width, per_word = word_layout(pack)
+    n_groups = -(-(n_pe + R - 1) // per_word)
+    out = np.zeros((-(-Q // n_pe), n_groups, n_pe), np.int64)
+    for i in range(1, Q + 1):
+        for j in range(1, R + 1):
+            c, lane = (i - 1) // n_pe, (i - 1) % n_pe
+            g, slot = divmod(lane + j - 1, per_word)
+            out[c, g, lane] |= int(row[i, j]) << (slot * width)
+    return out.astype(np.uint32).view(np.int32)
+
+
+def _diag_words(row, Q, R, pack):
+    """The wavefront ('diag', pack) store of a row-major pointer matrix."""
+    import jax.numpy as jnp
+    from repro.core.traceback import pack_words
+    diag = np.zeros((Q + R, Q + 1), np.uint8)
+    for i in range(1, Q + 1):
+        for j in range(1, R + 1):
+            diag[i + j - 1, i] = row[i, j]
+    return np.asarray(pack_words(jnp.asarray(diag), pack))
+
+
 @pytest.mark.parametrize("Q,R,n_pe", [(8, 8, 4), (10, 7, 4),   # Q % n_pe != 0
                                       (5, 12, 8), (13, 13, 8)])
 def test_tb_reader_layout_parity(Q, R, n_pe, rng):
     row = np.zeros((Q + 1, R + 1), np.uint8)
     row[1:, 1:] = rng.integers(0, 7, (Q, R)).astype(np.uint8)
 
-    diag = np.zeros((Q + R, Q + 1), np.uint8)
-    n_chunks = -(-Q // n_pe)
-    chunk = np.zeros((n_chunks, n_pe, n_pe + R - 1), np.uint8)
-    for i in range(1, Q + 1):
-        for j in range(1, R + 1):
-            diag[i + j - 1, i] = row[i, j]
-            c, lane = (i - 1) // n_pe, (i - 1) % n_pe
-            chunk[c, lane, lane + j - 1] = row[i, j]
-
     import jax.numpy as jnp
     readers = {
         "row": _make_reader(jnp.asarray(row), "row"),
-        "diag": _make_reader(jnp.asarray(diag), "diag"),
-        "chunk": _make_reader(jnp.asarray(chunk), ("chunk", n_pe)),
+        "diag": _make_reader(jnp.asarray(_diag_words(row, Q, R, 1)),
+                             ("diag", 1)),
+        "chunk": _make_reader(jnp.asarray(_chunk_words(row, Q, R, n_pe, 1)),
+                              ("chunk", n_pe, 1)),
     }
     for i in range(1, Q + 1):
         for j in range(1, R + 1):
@@ -248,32 +267,24 @@ def test_tb_reader_layout_parity(Q, R, n_pe, rng):
 @pytest.mark.parametrize("pack", [2, 4])
 def test_packed_tb_readers_match_unpacked(pack, rng):
     """The ('diag', pack) and ('chunk', n_pe, pack) readers must decode
-    the lane-packed store to exactly the unpacked pointer values."""
+    the packed stores to exactly the unpacked pointer values."""
     import jax.numpy as jnp
-    from repro.core.traceback import pack_lanes
     Q, R, n_pe = 9, 11, 4
     width = 8 // pack
-    diag = np.zeros((Q + R, Q + 1), np.uint8)
-    chunk = np.zeros((-(-Q // n_pe), n_pe, n_pe + R - 1), np.uint8)
-    rngv = rng.integers(0, 1 << width, (Q, R)).astype(np.uint8)
-    for i in range(1, Q + 1):
-        for j in range(1, R + 1):
-            diag[i + j - 1, i] = rngv[i - 1, j - 1]
-            c, lane = (i - 1) // n_pe, (i - 1) % n_pe
-            chunk[c, lane, lane + j - 1] = rngv[i - 1, j - 1]
-    diag_p = np.asarray(pack_lanes(jnp.asarray(diag), pack))
-    chunk_p = np.asarray(pack_lanes(
-        jnp.moveaxis(jnp.asarray(chunk), 1, -1), pack))
-    chunk_p = np.moveaxis(chunk_p, -1, 1)
+    row = np.zeros((Q + 1, R + 1), np.uint8)
+    row[1:, 1:] = rng.integers(0, 1 << width, (Q, R)).astype(np.uint8)
     readers = {
-        "diag": _make_reader(jnp.asarray(diag), "diag"),
-        "diag_p": _make_reader(jnp.asarray(diag_p), ("diag", pack)),
-        "chunk_p": _make_reader(jnp.asarray(chunk_p), ("chunk", n_pe, pack)),
+        "row": _make_reader(jnp.asarray(row), "row"),
+        "diag_p": _make_reader(jnp.asarray(_diag_words(row, Q, R, pack)),
+                               ("diag", pack)),
+        "chunk_p": _make_reader(
+            jnp.asarray(_chunk_words(row, Q, R, n_pe, pack)),
+            ("chunk", n_pe, pack)),
     }
     for i in range(1, Q + 1):
         for j in range(1, R + 1):
             got = {k: int(f(i, j)) for k, f in readers.items()}
-            assert got["diag_p"] == got["chunk_p"] == got["diag"], (i, j, got)
+            assert got["diag_p"] == got["chunk_p"] == got["row"], (i, j, got)
 
 
 def test_plan_cache_keys_schedule_options(rng):
@@ -309,7 +320,7 @@ def test_traceback_bytes_estimator():
     seed_l = plan_mod.traceback_bytes(spec_l, 256, 256, strip=1, tb_pack=1)
     opt_l = plan_mod.traceback_bytes(spec_l, 256, 256, strip=1)
     opt_a = plan_mod.traceback_bytes(spec_a, 256, 256, strip=1)
-    assert seed_l == 512 * 257
+    assert seed_l == 512 * 65 * 4          # 257 lanes in 65 int32 words
     assert seed_l / opt_l == pytest.approx(4.0, rel=0.05)
     assert seed_l / opt_a == pytest.approx(2.0, rel=0.05)
     assert plan_mod.traceback_bytes(spec_v, 256, 256) == 0

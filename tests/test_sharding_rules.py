@@ -75,8 +75,7 @@ def test_hlo_cost_scan_trip_counts():
     c = hlo_cost.analyze(comp.as_text())
     assert c.flops == 7 * 2 * 64 ** 3
     # XLA's own analysis undercounts (documents why we parse ourselves)
-    from repro.compat import cost_analysis_dict
-    assert cost_analysis_dict(comp).get("flops", 0) < c.flops / 2
+    assert (comp.cost_analysis() or {}).get("flops", 0) < c.flops / 2
 
 
 def test_hlo_cost_nested_scan():

@@ -51,19 +51,21 @@ def test_ops_override_swaps_sam_convention():
 
 
 def test_pack_lanes_roundtrip(rng):
-    """pack_lanes slots decode back to the original pointers, including
+    """pack_words slots decode back to the original pointers, including
     a ragged lane count (zero-padded tail)."""
     import jax.numpy as jnp
-    from repro.core.traceback import _unpack, pack_lanes
+    from repro.core.traceback import pack_words, word_slot
     for pack in (1, 2, 4, 8):
         width = 8 // pack
-        lanes = 13                      # not a multiple of any pack > 1
+        per_word = 32 // width
+        lanes = 13                      # not a multiple of any word width
         ptr = rng.integers(0, 1 << width, lanes).astype(np.uint8)
-        packed = np.asarray(pack_lanes(jnp.asarray(ptr), pack))
-        assert packed.shape == (-(-lanes // pack),)
+        packed = np.asarray(pack_words(jnp.asarray(ptr), pack))
+        nw = -(-lanes // per_word)
+        assert packed.shape == (nw,) and packed.dtype == np.int32
         for i in range(lanes):
-            got = int(np.asarray(_unpack(jnp.asarray(packed[i // pack]),
-                                         i % pack, pack)))
+            got = int(np.asarray(word_slot(jnp.asarray(packed[i % nw]),
+                                           i // nw, pack)))
             assert got == int(ptr[i]), (pack, i)
 
 
