@@ -24,13 +24,13 @@ from .export import (to_chrome_trace, validate_chrome_trace,
                      write_chrome_trace)
 from .metrics import (COMPILE_LEDGER, REGISTRY, Counter, Gauge, Histogram,
                       MetricsRegistry, get_registry)
-from .trace import (annotate, counter, disable, enable, enabled, instant,
-                    span, traced)
+from .trace import (counter, disable, enable, enabled, instant, span,
+                    traced)
 
 __all__ = [
     "trace", "metrics", "export",
     "enable", "disable", "enabled", "span", "instant", "traced",
-    "counter", "annotate",
+    "counter",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "COMPILE_LEDGER", "get_registry",
     "to_chrome_trace", "write_chrome_trace", "validate_chrome_trace",

@@ -35,16 +35,19 @@ Usage::
     events = trace.snapshot()           # {"spans": [...], "counters": ...}
     trace.disable()
 
-The optional ``jax.profiler`` bridge (:func:`annotate`) brackets device
-launches with named ``TraceAnnotation``s so XLA's own profiler timeline
-carries the gateway's stage names; it is off unless
-:func:`enable_jax_bridge` is called (and harmlessly no-ops when the
-running jax has no profiler).
+The optional ``jax.profiler`` bridge (:func:`enable_jax_bridge`) makes
+every recorded span also open a ``TraceAnnotation`` of its name, so the
+profiler's host timeline carries the same spans on the device trace's
+clock.  A bridged span's args and its profiler event share a
+``span_id``, which pairs the two clocks span by span.  With tracing off
+the bridge costs nothing: ``span()`` returns the no-op before looking
+at it.
 """
 from __future__ import annotations
 
 import collections
 import functools
+import itertools
 import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
@@ -53,13 +56,13 @@ __all__ = [
     "Span", "CounterSample", "enable", "disable", "enabled", "span",
     "instant", "traced", "counter", "snapshot", "spans", "counters",
     "dropped", "clear", "enable_jax_bridge", "disable_jax_bridge",
-    "annotate",
 ]
 
 # -- the global switch -------------------------------------------------------
 # read on every span() call; writes only via enable()/disable()
 _ENABLED = False
-_JAX_BRIDGE = False
+_JAX_BRIDGE = None       # jax.profiler.TraceAnnotation while bridged
+_SPAN_IDS = itertools.count()
 
 _DEFAULT_CAPACITY = 4096
 _CAPACITY = _DEFAULT_CAPACITY
@@ -228,13 +231,40 @@ class _SpanCM:
         return self
 
 
+class _BridgedSpanCM(_SpanCM):
+    """A span that also opens a profiler ``TraceAnnotation`` of its name
+    around itself; both carry the same ``span_id``."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, cat: str, args: Optional[dict]):
+        k = next(_SPAN_IDS)
+        _SpanCM.__init__(self, name, cat, dict(args or (), span_id=k))
+        self._ann = _JAX_BRIDGE(name, span_id=k)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return _SpanCM.__enter__(self)
+
+    def __exit__(self, *exc):
+        _SpanCM.__exit__(self, *exc)
+        self._ann.__exit__(*exc)
+        return False
+
+
+def _span_cm(name: str, cat: str, args: Optional[dict]) -> _SpanCM:
+    if _JAX_BRIDGE is None:
+        return _SpanCM(name, cat, args)
+    return _BridgedSpanCM(name, cat, args)
+
+
 def span(name: str, cat: str = "gw", **args):
     """A context manager timing one named interval on this thread.
 
     Disabled tracing returns a shared no-op — the call is one branch."""
     if not _ENABLED:
         return _NOOP
-    return _SpanCM(name, cat, args or None)
+    return _span_cm(name, cat, args or None)
 
 
 def instant(name: str, cat: str = "gw", **args) -> None:
@@ -268,8 +298,7 @@ def traced(fn=None, *, name: Optional[str] = None, cat: str = "fn"):
         def wrapper(*a, **kw):
             if not _ENABLED:
                 return f(*a, **kw)
-            cm = _SpanCM(label, cat, None)
-            with cm:
+            with _span_cm(label, cat, None):
                 return f(*a, **kw)
         return wrapper
 
@@ -309,25 +338,13 @@ def snapshot() -> Dict[str, Any]:
 
 # -- the optional jax.profiler bridge ---------------------------------------
 def enable_jax_bridge() -> None:
-    """Bracket device launches with named ``jax.profiler``
-    ``TraceAnnotation``s (visible in XLA profiler timelines).  Off by
-    default; a jax without the profiler degrades to a no-op."""
+    """Open a ``jax.profiler.TraceAnnotation`` around every span recorded
+    from now on (off by default)."""
     global _JAX_BRIDGE
-    _JAX_BRIDGE = True
+    from jax.profiler import TraceAnnotation
+    _JAX_BRIDGE = TraceAnnotation
 
 
 def disable_jax_bridge() -> None:
     global _JAX_BRIDGE
-    _JAX_BRIDGE = False
-
-
-def annotate(name: str):
-    """A ``TraceAnnotation(name)`` when the jax bridge is on, else the
-    shared no-op context manager."""
-    if not _JAX_BRIDGE:
-        return _NOOP
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:
-        return _NOOP
+    _JAX_BRIDGE = None

@@ -28,6 +28,12 @@ from repro.obs import trace as obs_trace
 
 from . import registry
 
+# Names of the plan program's two phases in the compiled program's
+# ``op_name`` metadata (compile-time only: no device work, no switch), so
+# a profiler trace can split device time into fill and traceback.
+FILL_SCOPE = "plan.fill"
+TRACEBACK_SCOPE = "plan.traceback"
+
 
 def is_traced(*trees) -> bool:
     """True if any leaf of the given pytrees is a jax tracer — i.e. the
@@ -45,16 +51,18 @@ def align_impl(spec: T.DPKernelSpec, engine_fn: Callable, params,
     This is the single execution core: CompiledPlan jits it, and callers
     already inside a trace (vmap/jit/scan) inline it directly.
     """
-    res = engine_fn(spec, params, query, ref, q_len, r_len)
+    res = fill_impl(spec, engine_fn, params, query, ref, q_len, r_len)
     if with_traceback and spec.traceback is not None:
         max_len = query.shape[0] + ref.shape[0] + 1
-        return tb_mod.run(spec, res, max_len)
+        with jax.named_scope(TRACEBACK_SCOPE):
+            return tb_mod.run(spec, res, max_len)
     return T.Alignment(score=res.score, end_i=res.end_i, end_j=res.end_j)
 
 
 def fill_impl(spec: T.DPKernelSpec, engine_fn: Callable, params,
               query, ref, q_len=None, r_len=None) -> T.DPResult:
-    return engine_fn(spec, params, query, ref, q_len, r_len)
+    with jax.named_scope(FILL_SCOPE):
+        return engine_fn(spec, params, query, ref, q_len, r_len)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,13 +138,15 @@ def _build_fn(key: PlanKey, spec: T.DPKernelSpec,
         return engine_fn(spec, params, query, ref, q_len, r_len, **kw)
 
     def fn(params, queries, refs, q_lens, r_lens):
-        bound = jnp.max(q_lens + r_lens)
-        res = jax.vmap(eng, in_axes=(None, 0, 0, 0, 0, None))(
-            params, queries, refs, q_lens, r_lens, bound)
+        with jax.named_scope(FILL_SCOPE):
+            bound = jnp.max(q_lens + r_lens)
+            res = jax.vmap(eng, in_axes=(None, 0, 0, 0, 0, None))(
+                params, queries, refs, q_lens, r_lens, bound)
         if mode == "fill":
             return res
         if wtb:
-            return tb_mod.run_batched(spec, res, max_len=max_len)
+            with jax.named_scope(TRACEBACK_SCOPE):
+                return tb_mod.run_batched(spec, res, max_len=max_len)
         return T.Alignment(score=res.score, end_i=res.end_i,
                            end_j=res.end_j)
 
@@ -159,6 +169,7 @@ class CompiledPlan:
         self.calls = 0
         self.hits = 0          # cache hits after the initial miss
         self.compile_s = None  # trace+compile wall time of the first call
+        self._avals = None     # argument shapes of the first call
         fn = _build_fn(key, spec, engine_name)
 
         # no input buffer is donated: no output has the shape and dtype
@@ -202,6 +213,10 @@ class CompiledPlan:
             # first dispatch pays trace + compile synchronously; time it
             # (execution stays async, so this is compile-dominated)
             kstr = plan_key_str(self.key)
+            self._avals = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(jnp.shape(x),
+                                               jnp.result_type(x)),
+                (params, query, ref, q_len, r_len))
             with obs_trace.span("plan.compile", cat="plan", key=kstr):
                 t0 = time.perf_counter()
                 out = self._fn(params, query, ref, q_len, r_len)
@@ -214,6 +229,15 @@ class CompiledPlan:
                 self.compile_s)
             return out
         return self._fn(params, query, ref, q_len, r_len)
+
+    def compiled_text(self) -> str:
+        """XLA's text of the program this plan runs: its instructions as
+        a profiler trace names its ops, each with its ``op_name``
+        metadata.  Compiles again (a persistent-cache hit where the cache
+        is on); only after the plan's first call."""
+        if self._avals is None:
+            raise RuntimeError(f"{self!r} has not run yet")
+        return self._fn.lower(*self._avals).compile().as_text()
 
     def __repr__(self):
         return f"CompiledPlan({self.key}, calls={self.calls})"

@@ -107,6 +107,9 @@ class _AlignChannel(gateway_mod.Channel):
     def job_len(self, job: AlignRequest) -> int:
         return len(job.query) + len(job.ref)
 
+    def job_cells(self, job: AlignRequest) -> int:
+        return len(job.query) * len(job.ref)
+
     def block_for(self, bucket) -> int:
         return self.svc.block_for(self.name, bucket)
 

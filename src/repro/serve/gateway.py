@@ -178,7 +178,9 @@ class InflightBatch:
     writes results for jobs still on that generation (a re-dispatch
     bumps ``job.gen``, so the stale original is discarded).  ``seq`` is
     the launching worker's dispatch counter (the FaultPlan coordinate);
-    ``launched_at`` feeds the per-batch harvest timeout.
+    ``launched_at`` feeds the per-batch harvest timeout.  ``block`` is
+    the rows of the launched plan at ``bucket`` (the grown bucket of a
+    coalesced batch).
     """
     worker: str
     kernel: str                      # channel name (kernel for align)
@@ -189,6 +191,7 @@ class InflightBatch:
     cancelled: bool = False
     seq: int = -1
     launched_at: Optional[float] = None
+    block: int = 0
 
 
 # -- the channel adapter ----------------------------------------------------
@@ -223,6 +226,11 @@ class Channel:
 
     def job_done(self, job) -> bool:
         return job.result is not None
+
+    def job_cells(self, job) -> int:
+        """DP cells the job asks for (``len(query) * len(ref)``); 0 for a
+        channel that does not count cells."""
+        return 0
 
     def deadline_of(self, job) -> Optional[float]:
         return getattr(job, "deadline", None)
@@ -536,19 +544,18 @@ class Gateway:
                     survivors: List = []
                     out = None
                 else:
-                    with obs_trace.annotate(f"gw.launch/{name}"):
-                        survivors, out = ch.launch(bucket, jobs, block)
+                    survivors, out = ch.launch(bucket, jobs, block)
         except BaseException as exc:
             with self._lock:
                 self._recover_jobs(ch, jobs, exc, count_attempt=True,
                                    worker=worker)
             raise
-        self._observe_batch_shape(ch, bucket, jobs, block)
+        self._observe_batch_shape(ch, jobs, block)
         ib = InflightBatch(worker=worker, kernel=name, bucket=bucket,
                            reqs=survivors,
                            gens=[j.gen for j in survivors], out=out,
                            cancelled=out is None, seq=seq,
-                           launched_at=self._clock())
+                           launched_at=self._clock(), block=block)
         with self._lock:
             self.inflight.setdefault(worker, []).append(ib)
             rec = ch.record(bucket, len(jobs) if degraded else len(survivors),
@@ -558,20 +565,12 @@ class Gateway:
             self.dispatches.append(rec)
         return ib
 
-    def _observe_batch_shape(self, ch: Channel, bucket, jobs,
-                             block: int) -> None:
-        """Occupancy / padding-waste histograms for one launched batch.
-        Waste uses ``job_len`` against the bucket perimeter when the
-        channel exposes lengths, else falls back to empty-row fraction."""
+    def _observe_batch_shape(self, ch: Channel, jobs, block: int) -> None:
+        """The occupancy histogram: jobs per launched block over its
+        rows."""
         occ = len(jobs) / block if block else 1.0
         self._metrics.histogram(
             "gw_batch_occupancy", channel=ch.name).observe(occ)
-        used = sum(ch.job_len(j) for j in jobs)
-        denom = block * (int(bucket[0]) + int(bucket[1]))
-        waste = (max(0.0, 1.0 - used / denom) if used > 0 and denom > 0
-                 else max(0.0, 1.0 - occ))
-        self._metrics.histogram(
-            "gw_padding_waste", channel=ch.name).observe(waste)
 
     def _harvest(self, item, ib: InflightBatch) -> int:
         """Block on one launched batch and land its results.
@@ -580,13 +579,18 @@ class Gateway:
         (``gen`` mismatch) or already resolved keeps its authoritative
         result.  On failure the still-incomplete jobs go through the
         bounded-retry requeue; the batch always leaves ``inflight``.
+
+        Where the channel counts cells, a batch that landed jobs records
+        the cells they asked for (``cells_useful``) and the cells its
+        plan computed, rows x q-bucket x r-bucket (``cells_launched``),
+        on its span and in ``gw_cells_useful_total`` and
+        ``gw_cells_launched_total``: padding is one minus their ratio.
         """
         ch = self._resolve_channel(item[0])
         fp = self.fault_plan
-        done = 0
+        done = cells = launched = 0
         sp = obs_trace.span("gw.harvest", cat="gateway", worker=ib.worker,
                             channel=ch.name, seq=ib.seq, n=len(ib.reqs))
-        t_h0 = self._clock()
         try:
             with sp:
                 if not ib.cancelled:
@@ -607,12 +611,17 @@ class Gateway:
                             if job.gen != gen or ch.job_done(job):
                                 continue         # stale or double write
                             units = ch.land(job, i, host)
+                            cells += ch.job_cells(job)
                             if units:
                                 done += units
                                 self._pending -= units
                                 self.stats["completed"] += units
                                 self._observe_latency(job, "completed")
                 sp.set(done=done)
+                if cells:
+                    launched = (ib.block * int(ib.bucket[0])
+                                * int(ib.bucket[1]))
+                    sp.set(cells_useful=cells, cells_launched=launched)
         except BaseException as exc:
             with self._lock:
                 self._requeue_incomplete(ib, exc=exc, count_attempt=True)
@@ -623,14 +632,9 @@ class Gateway:
             self.monitor.beat(ib.worker)
         if done:
             self._metrics.counter("gw_completed_total").inc(done)
-        if not ib.cancelled and ib.reqs:
-            # device-level throughput: padded cells the batch filled
-            cells = len(ib.reqs) * int(ib.bucket[0]) * int(ib.bucket[1])
-            self._metrics.counter("gw_cells_total").inc(cells)
-            dt = self._clock() - t_h0
-            if dt > 0.0:
-                self._metrics.histogram("gw_gcups").observe(
-                    cells / dt / 1e9)
+        if cells:
+            self._metrics.counter("gw_cells_useful_total").inc(cells)
+            self._metrics.counter("gw_cells_launched_total").inc(launched)
         return done
 
     def _forget(self, ib: InflightBatch) -> None:

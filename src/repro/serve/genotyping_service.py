@@ -116,6 +116,9 @@ class _PairHMMChannel(gateway_mod.Channel):
     def job_len(self, job: _PairJob) -> int:
         return len(job.query) + len(job.ref)
 
+    def job_cells(self, job: _PairJob) -> int:
+        return len(job.query) * len(job.ref)
+
     def job_rid(self, job: _PairJob):
         return job.req.rid
 

@@ -62,6 +62,40 @@ def test_disabled_path_emits_nothing():
     assert trace.span("a") is trace.span("b") is trace._NOOP
 
 
+@pytest.fixture
+def bridge():
+    trace.enable_jax_bridge()
+    yield
+    trace.disable_jax_bridge()
+
+
+def test_bridge_with_tracing_off_is_still_the_shared_noop(bridge):
+    assert not trace.enabled()
+    assert trace.span("a") is trace.span("b") is trace._NOOP
+    with trace.span("x", cat="t", a=1):
+        pass
+    assert trace.spans() == []
+
+
+def test_bridged_spans_carry_distinct_ids(bridge):
+    """With tracing and the bridge on, every span (decorated ones too)
+    opens a profiler annotation and records the id it gave it."""
+    trace.enable()
+
+    @trace.traced(name="f")
+    def f():
+        with trace.span("inner", cat="t", a=1):
+            pass
+
+    f()
+    with trace.span("x", cat="t") as sp:
+        sp.set(b=2)
+    by = {s.name: s.args for s in trace.spans()}
+    assert by["inner"]["a"] == 1 and by["x"]["b"] == 2
+    ids = [a["span_id"] for a in by.values()]
+    assert len(set(ids)) == 3
+
+
 def test_enable_disable_round_trip():
     trace.enable()
     with trace.span("on", cat="t"):
@@ -387,6 +421,48 @@ def test_shed_dead_letter_attributed_to_submit(rng):
     assert m["reconcile"]["submitted"] == 2
     svc.drain()
     assert svc.metrics()["reconcile"]["ok"]
+
+
+def _genotype_site(rid, seed):
+    from repro.data.synthetic import sample_site
+    from repro.serve import GenotypeRequest
+    site = sample_site(seed=seed, n_reads=6, genotype=(0, 1))
+    return GenotypeRequest(rid=rid, reads=site.reads,
+                           haplotypes=site.haplotypes)
+
+
+@pytest.mark.parametrize("service", ["alignment", "genotyping"])
+def test_harvest_spans_count_the_cells_of_what_they_landed(service, rng):
+    """Each harvested batch records the cells its jobs asked for and the
+    cells its plan computed; the counters hold the same sums."""
+    trace.enable()
+    if service == "alignment":
+        svc = AlignmentService(max_len=32, block=4)
+        reqs = [AlignRequest(
+            rid=i, kernel="global_affine",
+            query=rng.integers(0, 4, int(n)).astype(np.uint8),
+            ref=rng.integers(0, 4, int(n) + 3).astype(np.uint8))
+            for i, n in enumerate(rng.integers(4, 28, 11))]
+        futs = [svc.submit(r) for r in reqs]
+        pairs = [(r.query, r.ref) for r in reqs]
+    else:
+        from repro.serve import GenotypingService
+        svc = GenotypingService(max_len=64, block=4)
+        sites = [_genotype_site(k, 40 + k) for k in range(2)]
+        futs = [svc.submit(s) for s in sites]
+        pairs = [(q, h) for s in sites for q in s.reads
+                 for h in s.haplotypes]
+    svc.drain()
+    assert all(f.done() and "failed" not in f.result() for f in futs)
+    harvests = [s.args for s in trace.spans() if s.name == "gw.harvest"]
+    useful = sum(a["cells_useful"] for a in harvests)
+    launched = sum(a["cells_launched"] for a in harvests)
+    assert useful == sum(len(q) * len(r) for q, r in pairs)
+    assert all(0 < a["cells_useful"] <= a["cells_launched"]
+               for a in harvests)
+    counters = svc.metrics()["metrics"]["counters"]
+    assert counters["gw_cells_useful_total"] == useful
+    assert counters["gw_cells_launched_total"] == launched
 
 
 def test_dump_trace_writes_valid_file(tmp_path, rng):

@@ -443,3 +443,60 @@ def test_resolve_engine_opts_shim_warns_and_matches():
         legacy = plan_mod.resolve_engine_opts(spec, "wavefront", strip=4)
     full = plan_mod.resolve_engine_options(spec, "wavefront", {"strip": 4})
     assert legacy == (full["strip"], full["tb_pack"])
+
+
+# ---------------------------------------------------------------------------
+# the plan program's named phases
+# ---------------------------------------------------------------------------
+def _op_name_parts(text):
+    import re
+    return {part for name in re.findall(r'op_name="([^"]*)"', text)
+            for part in name.split("/")}
+
+
+def _ran_plan(engine, batch, rng):
+    import jax.numpy as jnp
+    spec, params = kernels_zoo.make("local_affine")
+    plan = plan_mod.get_plan(spec, engine, (16,), (16,), batch_size=batch)
+    shape = (16,) if batch is None else (batch, 16)
+    q = jnp.asarray(rng.integers(0, 4, shape).astype(np.uint8))
+    n = jnp.full(shape[:-1], 14, jnp.int32)
+    plan(params, q, q, n, n)
+    return plan
+
+
+@pytest.mark.parametrize("engine,batch", [("wavefront", 2),
+                                          ("pallas_interpret", 2),
+                                          ("wavefront", None)])
+def test_plan_program_names_its_phases(engine, batch, rng):
+    """The compiled program's op_name metadata names the fill and the
+    traceback, so a profiler trace can split device time between them."""
+    parts = _op_name_parts(_ran_plan(engine, batch, rng).compiled_text())
+    assert {plan_mod.FILL_SCOPE, plan_mod.TRACEBACK_SCOPE} <= parts
+
+
+def test_phase_scopes_leave_instruction_names(rng, monkeypatch):
+    """The scopes are metadata only: the compiled instructions, whose
+    names a trace shows, are those of the program without them."""
+    import contextlib
+    import re
+    import jax
+
+    def names(text):
+        return re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) =", text, re.M)
+
+    scoped = _ran_plan("wavefront", 2, rng)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = plan_mod.CompiledPlan(scoped.key, scoped.spec, "wavefront")
+    plain._avals = scoped._avals
+    assert names(plain.compiled_text()) == names(scoped.compiled_text())
+    assert plan_mod.FILL_SCOPE not in _op_name_parts(plain.compiled_text())
+
+
+def test_compiled_text_needs_a_first_call():
+    spec, _ = kernels_zoo.make("local_affine")
+    cached = plan_mod.get_plan(spec, "wavefront", (8,), (8,), batch_size=2)
+    plan = plan_mod.CompiledPlan(cached.key, spec, "wavefront")
+    with pytest.raises(RuntimeError, match="has not run"):
+        plan.compiled_text()

@@ -111,3 +111,48 @@ def test_service_plan_lowers_without_unusable_donation(one_chip):
         warnings.simplefilter("always")
         _compile(plan, spec, params, 256, 8, one_chip, one_chip)
     assert not [w for w in caught if "donated" in str(w.message)]
+
+
+def _op_name_parts(text):
+    import re
+    return {part for name in re.findall(r'op_name="([^"]*)"', text)
+            for part in name.split("/")}
+
+
+def _long_read_plan(bucket):
+    """The served plan of a long-read deployment (local affine, a 2 GiB
+    traceback budget) at one bucket, as the chip compiles it."""
+    from repro.serve import AlignmentService
+    svc = AlignmentService(max_len=16384, tb_budget_bytes=2 << 30)
+    rows = svc.block_for("local_affine", (bucket, bucket))
+    return _plan("local_affine", "wavefront", bucket, rows, strip=8), rows
+
+
+@pytest.mark.parametrize("bucket", [1024, 2048, 4096, 8192, 16384])
+def test_long_read_plans_name_their_phases(one_chip, bucket):
+    (spec, params, plan), rows = _long_read_plan(bucket)
+    text = _compile(plan, spec, params, bucket, rows, one_chip,
+                    one_chip).as_text()
+    assert {plan_mod.FILL_SCOPE, plan_mod.TRACEBACK_SCOPE} <= \
+        _op_name_parts(text)
+
+
+def test_phase_scopes_leave_the_chips_instruction_names(one_chip,
+                                                        monkeypatch):
+    """A trace names device ops by instruction: the scopes must leave
+    every instruction of the compiled program as it was."""
+    import contextlib
+    import re
+
+    def names(text):
+        return re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) =", text, re.M)
+
+    (spec, params, plan), rows = _long_read_plan(16384)
+    scoped = _compile(plan, spec, params, 16384, rows, one_chip, one_chip)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    (_, _, plain_plan), _ = _long_read_plan(16384)
+    plain = _compile(plain_plan, spec, params, 16384, rows, one_chip,
+                     one_chip)
+    assert names(plain.as_text()) == names(scoped.as_text())
+    assert plan_mod.FILL_SCOPE not in _op_name_parts(plain.as_text())
