@@ -461,18 +461,21 @@ class AlignmentService(Gateway):
 
         A bucket ``b2`` dominates when both sides are >= ``bucket`` — its
         requests fit after padding to ``b2``, so the combined batch
-        dispatches at the elementwise-max bucket.  Closest (smallest
-        dominating) buckets are drained first to keep padding waste low.
-        Under a memory budget the row cap is re-evaluated at each grown
-        bucket (``block_for``), so coalescing into a bigger bucket can
-        never launch a batch whose traceback store exceeds the budget.
+        dispatches at the elementwise-max bucket.  Only a donor that
+        cannot fill its own block gives: a small request must never take
+        the row of one that would have launched in a full block.
+        Closest (smallest dominating) buckets are drained first to keep
+        padding waste low.  Under a memory budget the row cap is
+        re-evaluated at each grown bucket (``block_for``), so coalescing
+        into a bigger bucket can never launch a batch whose traceback
+        store exceeds the budget.
         """
         out_bucket = bucket
         donors = sorted(
-            (b2 for (k2, b2) in self.queues
+            (b2 for (k2, b2), queue in self.queues.items()
              if k2 == kernel and b2 != bucket
              and b2[0] >= bucket[0] and b2[1] >= bucket[1]
-             and self.queues[(k2, b2)]),
+             and 0 < len(queue) < self.block_for(kernel, b2)),
             key=lambda b2: b2[0] * b2[1])
         for b2 in donors:
             grown = (max(out_bucket[0], b2[0]), max(out_bucket[1], b2[1]))

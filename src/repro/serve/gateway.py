@@ -285,8 +285,10 @@ class Gateway:
 
     Services subclass this and register :class:`Channel` adapters; the
     gateway owns admission (``max_pending`` + ``backpressure``
-    block/raise/shed), longest-first block formation with the
-    anti-starvation ``STALE_AFTER`` guard, pipelined launch/harvest
+    block/raise/shed), the launch order (fullest block first across
+    queue keys, with the ``QUEUE_STALE_AFTER`` starvation bound), longest-
+    first block formation within a key with the anti-starvation
+    ``STALE_AFTER`` guard, pipelined launch/harvest
     (inline via ``wait``/``drain``, concurrent via ``serve``), heartbeat
     bookkeeping, generation counters, bounded retries, deadlines, fault
     injection and the dead-letter queue.  All shared state — queues,
@@ -300,6 +302,9 @@ class Gateway:
     # batch pops a job may be passed over (by longest-first block
     # formation) before it jumps to the front of its queue
     STALE_AFTER = 4
+    # launches a queue key holding ready jobs may be passed over (by
+    # fullest-block-first launch order) before it is served next
+    QUEUE_STALE_AFTER = 16
 
     # admission nouns for backpressure messages ("request" / "site")
     _unit = ("request", "requests")
@@ -356,6 +361,7 @@ class Gateway:
         self._lock = threading.RLock()
         self._qinfo: Dict[object, tuple] = {}    # key -> (channel, bucket)
         self._qorder: Dict[object, tuple] = {}   # key -> stable sort key
+        self._passed: Dict[object, int] = {}     # key -> launches passed over
         self._gw_channels: Dict[str, Channel] = {}
         self._seq: Dict[str, int] = {}           # per-worker launch counter
         self._killed: set = set()                # FaultPlan-killed workers
@@ -425,65 +431,113 @@ class Gateway:
     # -- batch formation ------------------------------------------------------
     def _next_batch(self, worker: str = "w0"):
         """Pop the next ``(channel, bucket, jobs, coalesced, rows)``
-        batch, smallest bucket first per channel, or None when every
-        queue is empty (or cooling down in retry backoff)."""
+        batch, or None when every queue is empty (or cooling down in
+        retry backoff).
+
+        Fullest block first: the launch goes to the queue key whose ready
+        jobs (those past their retry-backoff gate) fill the largest share
+        of its block, ``min(ready, rows) / rows`` with ``rows`` from the
+        channel's ``block_for``.  Under a memory budget a block costs
+        about the same device time at every bucket, so what a launch
+        carries, not its bucket, sets the throughput.  Equal shares fall
+        back to channel, then smallest bucket area first.
+
+        A key counts the launches that passed it over while it held
+        ready jobs.  One passed over ``QUEUE_STALE_AFTER`` times is
+        served next whatever its share; with several keys waiting, the
+        most passed over is served as soon as waiting longer would take
+        one of them past that bound, so none ever is.
+        """
         sp = obs_trace.span("gw.form", cat="gateway", worker=worker)
         with sp, self._lock:
             self._sample_queues()
             now = self._clock()
+            rows_of: Dict[object, int] = {}
+            share: Dict[object, float] = {}       # in stable key order
             for key in sorted((k for k, q in self.queues.items() if q),
                               key=self._qorder.__getitem__):
                 ch, bucket = self._qinfo[key]
-                queue = self.queues[key]
-                # drop jobs resolved elsewhere (dead-lettered sites,
-                # stale duplicates); dead-letter expired deadlines
-                live = []
-                for j in queue:
-                    if ch.job_done(j):
-                        continue
-                    dl = ch.deadline_of(j)
-                    if dl is not None and now >= dl:
-                        self._dead_letter(ch, j, DeadlineExceeded(
-                            f"{ch.name}/{ch.job_rid(j)}: deadline expired "
-                            f"{now - dl:.3f}s ago before dispatch"),
-                            worker=worker)
-                        continue
-                    live.append(j)
-                queue[:] = live
-                if not queue:
-                    continue
-                block = ch.block_for(bucket)
-                # longest-first within a bounded arrival window: blocks
-                # come out length-homogeneous (the engine's early-exit
-                # fill stops at the *block max* wavefront).  A
-                # passed-over counter guarantees progress under
-                # sustained arrivals: a job out-sorted STALE_AFTER times
-                # jumps to the front regardless of length, so no future
-                # can be starved by a stream of longer requests.
-                w = min(len(queue), 4 * block)
-                queue[:w] = sorted(
-                    queue[:w],
-                    key=lambda j: (j.waits < self.STALE_AFTER,
-                                   -ch.job_len(j)))
-                jobs: List = []
-                i = 0
-                while i < len(queue) and len(jobs) < block:
-                    if queue[i].not_before <= now:   # retry backoff gate
-                        jobs.append(queue.pop(i))
-                    else:
-                        i += 1
-                if not jobs:
-                    continue                         # whole key cooling down
-                for j in queue[:max(0, w - len(jobs))]:
-                    j.waits += 1
-                coalesced = False
-                if not queue and len(jobs) < block:
-                    bucket, block, coalesced = ch.coalesce(
-                        bucket, jobs, block)
-                sp.set(channel=ch.name, bucket=list(bucket), n=len(jobs))
-                return ch.name, bucket, jobs, coalesced, block
-            sp.drop()          # idle poll: keep worker tracks span-clean
-            return None
+                ready = self._ready(ch, self.queues[key], now, worker)
+                if ready:
+                    rows_of[key] = ch.block_for(bucket)
+                    share[key] = min(ready, rows_of[key]) / rows_of[key]
+            if not share:
+                self._passed.clear()
+                sp.drop()          # idle poll: keep worker tracks span-clean
+                return None
+            key, reason = self._choose(share)
+            ch, bucket = self._qinfo[key]
+            block = rows_of[key]
+            before = {k: len(self.queues[k]) for k in share}
+            queue = self.queues[key]
+            # longest-first within a bounded arrival window: blocks
+            # come out length-homogeneous (the engine's early-exit
+            # fill stops at the *block max* wavefront).  A
+            # passed-over counter guarantees progress under
+            # sustained arrivals: a job out-sorted STALE_AFTER times
+            # jumps to the front regardless of length, so no future
+            # can be starved by a stream of longer requests.
+            w = min(len(queue), 4 * block)
+            queue[:w] = sorted(
+                queue[:w],
+                key=lambda j: (j.waits < self.STALE_AFTER,
+                               -ch.job_len(j)))
+            jobs: List = []
+            i = 0
+            while i < len(queue) and len(jobs) < block:
+                if queue[i].not_before <= now:   # retry backoff gate
+                    jobs.append(queue.pop(i))
+                else:
+                    i += 1
+            for j in queue[:max(0, w - len(jobs))]:
+                j.waits += 1
+            coalesced = False
+            if not queue and len(jobs) < block:
+                bucket, block, coalesced = ch.coalesce(bucket, jobs, block)
+            # keys that gave no job to this launch were passed over
+            self._passed = {k: self._passed.get(k, 0) + 1 for k in share
+                            if len(self.queues[k]) == before[k]}
+            sp.set(channel=ch.name, bucket=list(bucket), n=len(jobs),
+                   occupancy=len(jobs) / block, reason=reason)
+            self._metrics.counter("gw_form_reason_total",
+                                  reason=reason).inc()
+            return ch.name, bucket, jobs, coalesced, block
+
+    def _ready(self, ch: Channel, queue: List, now: float,
+               worker: str) -> int:
+        """Drop jobs resolved elsewhere (dead-lettered sites, stale
+        duplicates) and dead-letter expired deadlines from ``queue``;
+        returns how many of the rest are past their retry-backoff gate.
+        Caller holds the lock."""
+        live = []
+        for j in queue:
+            if ch.job_done(j):
+                continue
+            dl = ch.deadline_of(j)
+            if dl is not None and now >= dl:
+                self._dead_letter(ch, j, DeadlineExceeded(
+                    f"{ch.name}/{ch.job_rid(j)}: deadline expired "
+                    f"{now - dl:.3f}s ago before dispatch"),
+                    worker=worker)
+                continue
+            live.append(j)
+        queue[:] = live
+        return sum(1 for j in live if j.not_before <= now)
+
+    def _choose(self, share: Dict[object, float]) -> Tuple[object, str]:
+        """The key to launch from, and why: ``"stale"`` (the starvation
+        bound), ``"fullest"`` (the one highest share) or ``"tie"`` (the
+        highest share is several keys', the first in stable order).
+        Caller holds the lock."""
+        waiting = sorted(share, key=lambda k: -self._passed.get(k, 0))
+        # the i-th most passed-over key can wait at most
+        # QUEUE_STALE_AFTER - passes more launches, behind i others
+        if any(self._passed.get(k, 0) >= self.QUEUE_STALE_AFTER - i
+               for i, k in enumerate(waiting)):
+            return waiting[0], "stale"
+        best = max(share.values())
+        top = [k for k, s in share.items() if s == best]
+        return top[0], ("tie" if len(top) > 1 else "fullest")
 
     def _sample_queues(self) -> None:
         """Per-channel queue-depth gauges plus the Perfetto counter

@@ -5,17 +5,22 @@ The alignment and genotyping services are used as the concrete gateways
 (they are thin channels over ``serve.gateway.Gateway``); the invariants
 under test are the gateway's: deterministic FaultPlan decisions, bounded
 retries ending in typed dead letters, deadline expiry, newest-first
-shedding, degrade-to-myers answers, and kill-then-recover with zero
-double completions.
+shedding, degrade-to-myers answers, kill-then-recover with zero
+double completions, and the launch order (fullest block first, bounded
+passes over any queue).
 """
 from __future__ import annotations
+
+import collections
+import dataclasses
 
 import numpy as np
 import pytest
 
-from repro.serve import (AlignRequest, AlignmentService, FaultPlan,
-                         GenotypeRequest, GenotypingService, InjectedFault,
-                         WorkerKilled)
+from repro.obs import trace as obs_trace
+from repro.serve import (AlignRequest, AlignmentService, Channel, FaultPlan,
+                         Gateway, GenotypeRequest, GenotypingService,
+                         InjectedFault, WorkerKilled)
 
 
 def _req(rid, rng, n=12, kernel="global_affine"):
@@ -264,3 +269,145 @@ def test_serve_elastic_respawns_killed_worker(rng):
     assert stats["killed"] and stats["killed"][0]["worker"] == "w0"
     assert stats["respawned"]               # a replacement was spawned
     assert svc._pending == 0 and svc.inflight == {}
+
+
+# -- launch order: fullest block first ---------------------------------------
+def _sized(rid, rng, n):
+    return AlignRequest(rid=rid, kernel="global_affine",
+                        query=rng.integers(0, 4, n).astype(np.uint8),
+                        ref=rng.integers(0, 4, n).astype(np.uint8))
+
+
+def test_full_bigger_bucket_launches_before_partial_smaller(rng):
+    svc = AlignmentService(max_len=64, block=4)
+    for i in range(2):
+        svc.submit(_sized(i, rng, 12))           # bucket 16: half a block
+    for i in range(2, 6):
+        svc.submit(_sized(i, rng, 50))           # bucket 64: a full block
+    _, bucket, jobs, coalesced, rows = svc._next_batch()
+    assert bucket == (64, 64) and len(jobs) == rows == 4 and not coalesced
+    _, bucket, jobs, coalesced, _ = svc._next_batch()
+    assert bucket == (16, 16) and len(jobs) == 2 and not coalesced
+    assert svc._next_batch() is None
+
+
+def test_equal_occupancy_falls_back_to_smallest_area(rng):
+    svc = AlignmentService(max_len=256, block=4, coalesce=False)
+    for i, n in enumerate([200, 180, 50, 40, 14, 12]):
+        svc.submit(_sized(i, rng, n))            # two rows at 256, 64, 16
+    order = [svc._next_batch()[1] for _ in range(3)]
+    assert order == [(16, 16), (64, 64), (256, 256)]
+
+
+def test_coalescing_skips_donors_that_fill_their_own_block(rng):
+    svc = AlignmentService(max_len=256, block=4)
+    for i in range(4):
+        svc.submit(_sized(i, rng, 50))           # bucket 64: fills a block
+    for i in range(4, 6):
+        svc.submit(_sized(i, rng, 200))          # bucket 256: cannot
+    small = [_sized(9, rng, 12)]
+    grown = svc._coalesce_batch("global_affine", (16, 16), small, 4)
+    assert grown == (256, 256) and len(small) == 3
+    assert len(svc.queues[("global_affine", (64, 64))]) == 4
+    assert not svc.queues[("global_affine", (256, 256))]
+
+
+@dataclasses.dataclass(eq=False)
+class _Job:
+    rid: int
+    bucket: tuple
+    result: object = None
+    gen: int = 0
+    waits: int = 0
+    attempts: int = 0
+    not_before: float = 0.0
+
+
+class _BudgetRowsChannel(Channel):
+    """Rows per bucket as a 2 GiB traceback budget sizes them; a launch
+    needs no device."""
+    name = "rows"
+    ROWS = {(1024, 1024): 256, (2048, 2048): 256, (4096, 4096): 127,
+            (8192, 8192): 31, (16384, 16384): 8}
+
+    def bucket_of(self, job):
+        return job.bucket
+
+    def block_for(self, bucket):
+        return self.ROWS[bucket]
+
+    def launch(self, bucket, jobs, block):
+        return jobs, bucket
+
+    def land(self, job, row, host):
+        job.result = {"row": row}
+        return 1
+
+
+def test_no_queue_is_passed_over_more_than_the_bound():
+    """A seeded closed loop of 128 outstanding jobs over five buckets,
+    whose small buckets never fill a block: each launch is checked
+    against an independent count of the launches that passed each key
+    over while it held jobs, and every bucket is still served in
+    proportion to its arrivals."""
+    gw = Gateway(pipeline_depth=1)
+    ch = gw.register_channel(_BudgetRowsChannel())
+    buckets = list(ch.ROWS)
+    mix = np.array([0.018, 0.051, 0.205, 0.36, 0.366])
+    rng = np.random.default_rng(15)
+    arrived, served = collections.Counter(), collections.Counter()
+    rid = 0
+
+    def submit():
+        nonlocal rid
+        job = _Job(rid, buckets[rng.choice(len(buckets), p=mix / mix.sum())])
+        arrived[job.bucket] += 1
+        rid += 1
+        gw._pending += 1
+        gw._push(ch, job)
+
+    passed = {}
+    worst = 0
+    for _ in range(128):
+        submit()
+    for _ in range(3000):
+        waiting = {k for k, q in gw.queues.items() if q}
+        item = gw._next_batch()
+        got = ch.queue_key(item[1])
+        passed = {k: passed.get(k, 0) + 1 for k in waiting if k != got}
+        worst = max([worst, *passed.values()])
+        assert worst <= Gateway.QUEUE_STALE_AFTER
+        gw._harvest(item, gw._launch("w0", item))
+        served.update(j.bucket for j in item[2])
+        while gw._pending < 128:
+            submit()
+    reasons = gw.metrics()["metrics"]["counters"]
+    assert reasons["gw_form_reason_total{reason=stale}"] > 0
+    assert worst == Gateway.QUEUE_STALE_AFTER     # the bound is reached
+    n_served, n_arrived = sum(served.values()), sum(arrived.values())
+    for b in buckets:
+        assert abs(served[b] / n_served - arrived[b] / n_arrived) < 0.03
+    assert gw.drain() == 128 and gw._pending == 0
+
+
+def test_form_span_and_counter_record_why_each_launch(rng):
+    svc = AlignmentService(max_len=256, block=4)
+    for i, n in enumerate([12, 14, 50, 40, 200, 210]):
+        svc.submit(_sized(i, rng, n))            # two rows at 16, 64, 256
+    obs_trace.clear()
+    obs_trace.enable()
+    try:
+        assert svc.drain() == 6
+    finally:
+        obs_trace.disable()
+    forms = [s.args for s in obs_trace.spans() if s.name == "gw.form"]
+    obs_trace.clear()
+    assert len(forms) == len(svc.dispatches) == 2
+    # three half-full keys tie: 16 goes first and takes 64's two rows;
+    # then 256 is the only key left
+    assert [f["reason"] for f in forms] == ["tie", "fullest"]
+    assert [f["occupancy"] for f in forms] == [1.0, 0.5]
+    counters = svc.metrics()["metrics"]["counters"]
+    by_reason = {k: v for k, v in counters.items()
+                 if k.startswith("gw_form_reason_total")}
+    assert sum(by_reason.values()) == len(svc.dispatches)
