@@ -25,6 +25,7 @@ overload degradation to the bit-parallel edit-distance screen
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
@@ -33,6 +34,7 @@ import numpy as np
 from repro.core import batch as core_batch, kernels_zoo
 from repro.core.kernels_zoo import edit as edit_kernel
 from repro.core.traceback import moves_to_cigar, raise_if_truncated
+from repro.obs import trace as obs_trace
 from repro.runtime import bucketing
 from repro.runtime import plan as plan_mod
 
@@ -127,9 +129,13 @@ class _AlignChannel(gateway_mod.Channel):
     def launch(self, bucket, reqs, block):
         svc = self.svc
         spec, params, sharded_fn = svc._channel(self.name)
-        qs, rs, ql, rl = svc._pad_batch(
-            reqs, bucket, spec.char_shape,
-            np.dtype(jnp.dtype(spec.char_dtype).name), block)
+        with obs_trace.span("gw.pad", cat="gateway", n=len(reqs)):
+            t_pad = time.monotonic()
+            qs, rs, ql, rl = svc._pad_batch(
+                reqs, bucket, spec.char_shape,
+                np.dtype(jnp.dtype(spec.char_dtype).name), block)
+            svc._metrics.counter("gw_host_seconds_total", phase="pad").inc(
+                time.monotonic() - t_pad)
         if svc._screenable(spec):
             # ladder rung 1: rejects resolve here; only survivors
             # (rebound into ``reqs`` so a failing main launch requeues

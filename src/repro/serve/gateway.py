@@ -639,6 +639,11 @@ class Gateway:
         plan computed, rows x q-bucket x r-bucket (``cells_launched``),
         on its span and in ``gw_cells_useful_total`` and
         ``gw_cells_launched_total``: padding is one minus their ratio.
+
+        Inside ``gw.harvest``, ``gw.materialize`` is the wait on the
+        device and the copy to the host, and ``gw.land`` (``n``: rows
+        landed) the host's work per row; the landing loop's time also
+        goes to ``gw_host_seconds_total{phase="land"}``, once per batch.
         """
         ch = self._resolve_channel(item[0])
         fp = self.fault_plan
@@ -658,19 +663,32 @@ class Gateway:
                             raise InjectedFault(
                                 f"harvest #{ib.seq} on worker {ib.worker!r} "
                                 f"({ch.name})")
-                    host = ch.materialize(ib.out)    # sync point: blocks
-                    with self._lock:
-                        for i, (job, gen) in enumerate(
-                                zip(ib.reqs, ib.gens)):
-                            if job.gen != gen or ch.job_done(job):
-                                continue         # stale or double write
-                            units = ch.land(job, i, host)
-                            cells += ch.job_cells(job)
-                            if units:
-                                done += units
-                                self._pending -= units
-                                self.stats["completed"] += units
-                                self._observe_latency(job, "completed")
+                    with obs_trace.span("gw.materialize", cat="gateway",
+                                        worker=ib.worker, channel=ch.name,
+                                        seq=ib.seq):
+                        host = ch.materialize(ib.out)    # sync: blocks
+                    landed = 0
+                    with obs_trace.span("gw.land", cat="gateway",
+                                        worker=ib.worker,
+                                        channel=ch.name) as land_sp:
+                        t_land = time.monotonic()
+                        with self._lock:
+                            for i, (job, gen) in enumerate(
+                                    zip(ib.reqs, ib.gens)):
+                                if job.gen != gen or ch.job_done(job):
+                                    continue     # stale or double write
+                                units = ch.land(job, i, host)
+                                landed += 1
+                                cells += ch.job_cells(job)
+                                if units:
+                                    done += units
+                                    self._pending -= units
+                                    self.stats["completed"] += units
+                                    self._observe_latency(job, "completed")
+                        self._metrics.counter(
+                            "gw_host_seconds_total", phase="land").inc(
+                                time.monotonic() - t_land)
+                        land_sp.set(n=landed)
                 sp.set(done=done)
                 if cells:
                     launched = (ib.block * int(ib.bucket[0])

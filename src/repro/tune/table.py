@@ -28,6 +28,7 @@ resolves to "no table" — a bad table must never break dispatch.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 import pathlib
@@ -130,9 +131,12 @@ _OVERRIDE_PATH: Optional[str] = None          # set_table("path")
 _CACHED: Optional[tuple] = None               # (path, mtime, table|None)
 
 
+@functools.lru_cache(maxsize=1)
 def default_path() -> pathlib.Path:
     """``TUNE_TABLE.json`` at the repo root (three levels above this
-    package: src/repro/tune -> repo)."""
+    package: src/repro/tune -> repo).  Resolved once: every launch looks
+    the table up, and each ``lstat`` of the resolution is a system call
+    that can hand the interpreter lock to another thread."""
     return pathlib.Path(__file__).resolve().parents[3] / DEFAULT_TABLE_NAME
 
 
@@ -185,8 +189,7 @@ def active_table() -> Optional[TuningTable]:
         return _load_cached(_OVERRIDE_PATH)
     if env:
         return _load_cached(env)
-    p = default_path()
-    return _load_cached(str(p)) if p.is_file() else None
+    return _load_cached(str(default_path()))     # None when absent
 
 
 def lookup(kernel: str, engine: str, bucket: tuple,

@@ -465,6 +465,72 @@ def test_harvest_spans_count_the_cells_of_what_they_landed(service, rng):
     assert counters["gw_cells_launched_total"] == launched
 
 
+def _short_reads(rng, n=11):
+    return [AlignRequest(
+        rid=i, kernel="global_affine",
+        query=rng.integers(0, 4, int(k)).astype(np.uint8),
+        ref=rng.integers(0, 4, int(k) + 3).astype(np.uint8))
+        for i, k in enumerate(rng.integers(4, 28, n))]
+
+
+def test_host_work_spans_nest_and_match_the_counter(rng):
+    """``gw.materialize`` and ``gw.land`` lie inside ``gw.harvest``, and
+    ``gw.pad`` inside ``gw.launch``; ``n`` counts the rows landed and
+    padded; the spans that were there keep their args; and
+    ``gw_host_seconds_total`` holds the pad and land time of each batch,
+    taken inside those spans."""
+    trace.enable()
+    svc = AlignmentService(max_len=32, block=4)
+    reqs = _short_reads(rng)
+    futs = [svc.submit(r) for r in reqs]
+    svc.drain()
+    assert all(f.done() and "cigar" in f.result() for f in futs)
+    spans = trace.spans()
+
+    def named(name):
+        return [s for s in spans if s.name == name]
+
+    def inside(child, parents):
+        return sum(p.tid == child.tid and p.t0 <= child.t0
+                   and child.t1 <= p.t1 for p in parents) == 1
+
+    harvests, launches = named("gw.harvest"), named("gw.launch")
+    mats, lands, pads = named("gw.materialize"), named("gw.land"), \
+        named("gw.pad")
+    assert len(mats) == len(lands) == len(harvests) > 1
+    assert len(pads) == len(launches)
+    assert all(inside(s, harvests) for s in mats + lands)
+    assert all(inside(s, launches) for s in pads)
+    for m, land in zip(mats, lands):
+        assert m.t1 <= land.t0          # the wait comes before the rows
+    assert sum(s.args["n"] for s in lands) == len(reqs)
+    assert [s.args["n"] for s in pads] == [s.args["n"] for s in launches]
+    assert all(set(s.args) == {"worker", "channel", "n"} for s in lands)
+    assert all(set(s.args) == {"worker", "channel", "seq", "n", "done",
+                               "cells_useful", "cells_launched"}
+               for s in harvests)
+    assert all(set(s.args) == {"worker", "channel", "seq", "n"}
+               for s in launches)
+    counters = svc.metrics()["metrics"]["counters"]
+    for phase, ss in (("pad", pads), ("land", lands)):
+        in_spans = sum(s.t1 - s.t0 for s in ss)
+        counted = counters[f"gw_host_seconds_total{{phase={phase}}}"]
+        assert 0 < counted <= in_spans
+        assert in_spans - counted < 1e-3 * len(ss)
+
+
+def test_host_seconds_are_counted_with_tracing_off(rng):
+    """The counter does not depend on tracing; no span is recorded."""
+    svc = AlignmentService(max_len=32, block=4)
+    futs = [svc.submit(r) for r in _short_reads(rng, 6)]
+    svc.drain()
+    assert all(f.done() for f in futs)
+    assert trace.spans() == []
+    counters = svc.metrics()["metrics"]["counters"]
+    assert counters["gw_host_seconds_total{phase=pad}"] > 0
+    assert counters["gw_host_seconds_total{phase=land}"] > 0
+
+
 def test_dump_trace_writes_valid_file(tmp_path, rng):
     trace.enable()
     svc = AlignmentService(max_len=16, block=2)

@@ -145,6 +145,23 @@ class TestTable:
         assert tune.lookup("global_linear", "wavefront",
                            (32, 32), 4) == {"strip": 2}
 
+    def test_default_lookup_costs_one_system_call(self, monkeypatch):
+        """``get_plan`` looks the table up at every launch.  Through the
+        default path a lookup makes one ``stat`` (the table's mtime
+        check) and resolves no path: on a host-bound served path each
+        system call can hand the interpreter lock to another thread."""
+        import os
+        tune.lookup("k", "wavefront", (32, 32), 4)
+        calls = []
+        stat, lstat = os.stat, os.lstat
+        monkeypatch.setattr(os, "stat", lambda p, *a, **k: (
+            calls.append(str(p)), stat(p, *a, **k))[1])
+        monkeypatch.setattr(os, "lstat", lambda p, *a, **k: (
+            calls.append(str(p)), lstat(p, *a, **k))[1])
+        for _ in range(5):
+            tune.lookup("k", "wavefront", (32, 32), 4)
+        assert calls == [str(tune.default_path())] * 5
+
     def test_corrupt_table_is_no_table(self, tmp_path, monkeypatch):
         path = tmp_path / "bad.json"
         path.write_text("{not json")
