@@ -245,27 +245,52 @@ def raise_if_truncated(alignment: T.Alignment) -> T.Alignment:
 # ---------------------------------------------------------------------------
 # Host-side utilities (not jitted)
 # ---------------------------------------------------------------------------
-def moves_to_cigar(moves, n_moves, ops=None) -> str:
-    """end->start move array -> CIGAR string (start->end order).
+_CIGAR_OPS = {T.MOVE_DIAG: "M", T.MOVE_UP: "D", T.MOVE_LEFT: "I"}
 
-    ``ops`` overrides the move -> op-letter map.  The default follows the
-    repo convention (MOVE_UP = query-consuming = 'D'); SAM emission with
-    the read on the query axis passes ``{MOVE_DIAG: 'M', MOVE_UP: 'I',
+
+def block_cigars(moves, n_moves, ops=None, rows=None) -> list[str]:
+    """A block's end->start move arrays -> every row's CIGAR (start->end).
+
+    ``moves`` is ``[rows, max_len]`` and ``n_moves`` the ``[rows]`` path
+    lengths; ``rows`` encodes only the first ``rows`` rows (the real rows
+    of a padded block).  A row with no moves gives ``""``.  ``ops``
+    overrides the move -> op-letter map.  The default follows the repo
+    convention (MOVE_UP = query-consuming = 'D'); SAM emission with the
+    read on the query axis passes ``{MOVE_DIAG: 'M', MOVE_UP: 'I',
     MOVE_LEFT: 'D'}`` instead (see ``repro.mapping.sam``).
 
-    One device->host transfer + numpy run-length encoding: never pulls
-    scalars across the device boundary one move at a time.
+    One device->host transfer and one run-length pass over the whole
+    block: run starts, run lengths and row boundaries are array
+    operations, and Python only spells each run and joins each row.
     """
     if ops is None:
-        ops = {T.MOVE_DIAG: "M", T.MOVE_UP: "D", T.MOVE_LEFT: "I"}
-    n = int(n_moves)
-    if n == 0:
-        return ""
-    mv = np.asarray(moves)[:n][::-1]          # single transfer, then numpy
-    starts = np.concatenate([[0], np.flatnonzero(np.diff(mv)) + 1])
-    ends = np.concatenate([starts[1:], [n]])
-    return "".join(f"{e - s}{ops[int(mv[s])]}"
-                   for s, e in zip(starts, ends))
+        ops = _CIGAR_OPS
+    n = np.asarray(n_moves, np.int64).reshape(-1)[:rows]
+    if not n.size or n.max() <= 0:
+        return [""] * n.size
+    mv = np.asarray(moves)[: n.size, : int(n.max())]
+    # a run starts at a path's first move and wherever the move changes
+    start = np.empty(mv.shape, bool)
+    start[:, 0] = True
+    np.not_equal(mv[:, 1:], mv[:, :-1], out=start[:, 1:])
+    start &= np.arange(mv.shape[1]) < n[:, None]
+    row, k = np.nonzero(start)        # row-major: each row's runs end->start
+    end = n[row]                      # a row's last run ends at its length
+    same = np.flatnonzero(row[1:] == row[:-1])
+    end[same] = k[same + 1]
+    # reversed, the rows run last to first and each row's runs start->end
+    runs = [f"{length}{ops[code]}" for length, code in
+            zip((end - k)[::-1].tolist(), mv[row, k][::-1].tolist())]
+    bounds = np.cumsum(np.bincount(row, minlength=n.size)[::-1]).tolist()
+    return ["".join(runs[a:b]) for a, b in
+            zip([0] + bounds[:-1], bounds)][::-1]
+
+
+def moves_to_cigar(moves, n_moves, ops=None) -> str:
+    """One end->start move array -> its CIGAR string (start->end order):
+    the one-row case of :func:`block_cigars`, with the same ``ops``."""
+    return block_cigars(np.asarray(moves)[None], np.asarray(n_moves),
+                        ops=ops)[0]
 
 
 def path_cells(alignment: T.Alignment):

@@ -33,7 +33,7 @@ import numpy as np
 
 from repro.core import batch as core_batch, kernels_zoo
 from repro.core.kernels_zoo import edit as edit_kernel
-from repro.core.traceback import moves_to_cigar, raise_if_truncated
+from repro.core.traceback import block_cigars, raise_if_truncated
 from repro.obs import trace as obs_trace
 from repro.runtime import bucketing
 from repro.runtime import plan as plan_mod
@@ -169,12 +169,25 @@ class _AlignChannel(gateway_mod.Channel):
             n_moves = np.asarray(out.n_moves)
         return score, end_i, end_j, moves, n_moves
 
-    def land(self, job: AlignRequest, i: int, host) -> int:
+    def prepare_landing(self, host, n):
+        """The batch's first ``n`` rows (the pad rows are left out) as
+        Python lists, with every row's CIGAR from one block-wide pass
+        (span ``gw.cigar``, ``n``: rows encoded)."""
         score, end_i, end_j, moves, n_moves = host
-        res = {"score": float(score[i]),
-               "end": (int(end_i[i]), int(end_j[i]))}
+        cigars = None
         if moves is not None:
-            res["cigar"] = moves_to_cigar(moves[i], int(n_moves[i]))
+            with obs_trace.span("gw.cigar", cat="gateway", channel=self.name,
+                                n=n):
+                cigars = block_cigars(moves, n_moves, rows=n)
+        return (score[:n].astype(np.float64).tolist(),
+                end_i[:n].astype(np.int64).tolist(),
+                end_j[:n].astype(np.int64).tolist(), cigars)
+
+    def land(self, job: AlignRequest, i: int, host) -> int:
+        score, end_i, end_j, cigars = host
+        res = {"score": score[i], "end": (end_i[i], end_j[i])}
+        if cigars is not None:
+            res["cigar"] = cigars[i]
         job.result = res
         return 1
 

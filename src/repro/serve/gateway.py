@@ -256,6 +256,12 @@ class Channel:
         lock); whatever it returns is handed to ``land`` per row."""
         return out
 
+    def prepare_landing(self, host, n: int):
+        """Block-wide work on one batch's host results before its rows
+        land (called inside ``gw.land``, outside the gateway lock, with
+        ``n`` the rows launched for jobs); returns what ``land`` reads."""
+        return host
+
     def land(self, job, row: int, host) -> int:
         """Write one row's result into its job; returns completed units."""
         raise NotImplementedError
@@ -642,8 +648,9 @@ class Gateway:
 
         Inside ``gw.harvest``, ``gw.materialize`` is the wait on the
         device and the copy to the host, and ``gw.land`` (``n``: rows
-        landed) the host's work per row; the landing loop's time also
-        goes to ``gw_host_seconds_total{phase="land"}``, once per batch.
+        landed) the host's work on the batch: the channel's block-wide
+        ``prepare_landing``, then the rows; that time also goes to
+        ``gw_host_seconds_total{phase="land"}``, once per batch.
         """
         ch = self._resolve_channel(item[0])
         fp = self.fault_plan
@@ -672,6 +679,7 @@ class Gateway:
                                         worker=ib.worker,
                                         channel=ch.name) as land_sp:
                         t_land = time.monotonic()
+                        host = ch.prepare_landing(host, len(ib.reqs))
                         with self._lock:
                             for i, (job, gen) in enumerate(
                                     zip(ib.reqs, ib.gens)):

@@ -152,6 +152,44 @@ def test_redispatch_discards_late_original_result(rng):
     assert req.result is first
 
 
+def test_block_landing_matches_per_row_and_skips_stale(rng, monkeypatch):
+    """A harvested block lands, for every request, the score, end cell and
+    CIGAR that a row-by-row read of the device arrays gives; a request
+    re-dispatched since the launch keeps no result from it, and the pad
+    rows land nowhere."""
+    from repro.core.traceback import moves_to_cigar
+    from repro.serve import alignment_service as svc_mod
+    svc = AlignmentService(max_len=32, block=8)
+    reqs = [AlignRequest(i, "global_affine",
+                         rng.integers(0, 4, int(k)).astype(np.uint8),
+                         rng.integers(0, 4, int(k) + 3).astype(np.uint8))
+            for i, k in enumerate(rng.integers(4, 13, 5))]
+    seen = {}
+    real_materialize = svc_mod._AlignChannel.materialize
+
+    def keep(self, out):
+        seen["host"] = real_materialize(self, out)
+        return seen["host"]
+
+    monkeypatch.setattr(svc_mod._AlignChannel, "materialize", keep)
+    item = ("global_affine", (16, 16), reqs, False, 8)
+    ib = svc._launch("w0", item)
+    reqs[2].gen += 1                        # re-dispatched since the launch
+    assert svc._harvest(item, ib) == 4
+    score, end_i, end_j, moves, n_moves = seen["host"]
+    assert moves.shape[0] == 8              # three pad rows
+    assert reqs[2].result is None
+    for i, r in enumerate(reqs):
+        if i == 2:
+            continue
+        assert r.result == {"score": float(score[i]),
+                            "end": (int(end_i[i]), int(end_j[i])),
+                            "cigar": moves_to_cigar(moves[i],
+                                                    int(n_moves[i]))}
+        assert type(r.result["score"]) is float
+        assert all(type(e) is int for e in r.result["end"])
+
+
 def test_drain_requeues_requests_on_dispatch_failure(rng, monkeypatch):
     """If dispatch raises, the popped requests must go back to the queues
     and nothing may linger in ``inflight`` (regression: lost requests)."""
@@ -195,15 +233,15 @@ def test_wait_requeues_window_on_harvest_failure(rng, monkeypatch):
         svc.submit(r)
     from repro.serve import alignment_service as svc_mod
     boom = {"armed": True}
-    real_cigar = svc_mod.moves_to_cigar
+    real_cigars = svc_mod.block_cigars
 
-    def exploding_cigar(moves, n_moves):
+    def exploding_cigars(*args, **kwargs):
         if boom["armed"]:
             boom["armed"] = False
             raise RuntimeError("injected harvest failure")
-        return real_cigar(moves, n_moves)
+        return real_cigars(*args, **kwargs)
 
-    monkeypatch.setattr(svc_mod, "moves_to_cigar", exploding_cigar)
+    monkeypatch.setattr(svc_mod, "block_cigars", exploding_cigars)
     with pytest.raises(RuntimeError, match="injected"):
         svc.drain()
     queued = [r for q in svc.queues.values() for r in q]

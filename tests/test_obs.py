@@ -474,9 +474,10 @@ def _short_reads(rng, n=11):
 
 
 def test_host_work_spans_nest_and_match_the_counter(rng):
-    """``gw.materialize`` and ``gw.land`` lie inside ``gw.harvest``, and
-    ``gw.pad`` inside ``gw.launch``; ``n`` counts the rows landed and
-    padded; the spans that were there keep their args; and
+    """``gw.materialize`` and ``gw.land`` lie inside ``gw.harvest``,
+    ``gw.cigar`` inside ``gw.land``, and ``gw.pad`` inside ``gw.launch``;
+    ``n`` counts the rows landed, encoded once per batch, and padded; the
+    spans that were there keep their args; and
     ``gw_host_seconds_total`` holds the pad and land time of each batch,
     taken inside those spans."""
     trace.enable()
@@ -501,6 +502,14 @@ def test_host_work_spans_nest_and_match_the_counter(rng):
     assert len(pads) == len(launches)
     assert all(inside(s, harvests) for s in mats + lands)
     assert all(inside(s, launches) for s in pads)
+    cigars = named("gw.cigar")
+    assert len(cigars) == len(lands)
+    assert all(inside(s, lands) for s in cigars)
+    assert all(set(s.args) == {"channel", "n"} for s in cigars)
+    for c in cigars:                    # every row launched, pads left out
+        land, = (s for s in lands if s.t0 <= c.t0 and c.t1 <= s.t1)
+        h, = (s for s in harvests if s.t0 <= c.t0 and c.t1 <= s.t1)
+        assert c.args["n"] == h.args["n"] >= land.args["n"]
     for m, land in zip(mats, lands):
         assert m.t1 <= land.t0          # the wait comes before the rows
     assert sum(s.args["n"] for s in lands) == len(reqs)

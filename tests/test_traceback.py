@@ -1,12 +1,15 @@
 """moves_to_cigar coverage beyond the all-match case: I/D run-length
-encoding, leading/trailing gaps, empty alignments, and op-map overrides."""
+encoding, leading/trailing gaps, empty alignments, and op-map overrides;
+block_cigars against it row by row."""
 from __future__ import annotations
+
+import re
 
 import numpy as np
 import pytest
 
 from repro.core import types as T
-from repro.core.traceback import moves_to_cigar
+from repro.core.traceback import block_cigars, moves_to_cigar
 
 _CODE = {"M": T.MOVE_DIAG, "D": T.MOVE_UP, "I": T.MOVE_LEFT}
 
@@ -48,6 +51,68 @@ def test_ops_override_swaps_sam_convention():
     sam_ops = {T.MOVE_DIAG: "M", T.MOVE_UP: "I", T.MOVE_LEFT: "D"}
     arr, n = enc("MMIIIMDD")       # default: I = MOVE_LEFT, D = MOVE_UP
     assert moves_to_cigar(arr, n, ops=sam_ops) == "2M3D1M2I"
+
+
+def _random_block(rng, rows=9, width=40, n=None):
+    """``[rows, width]`` moves of random ops and lengths (or ``n``), with
+    junk (every code, the stop code too) past each row's ``n_moves``."""
+    ops = (T.MOVE_DIAG, T.MOVE_UP, T.MOVE_LEFT)
+    moves = rng.integers(0, 4, (rows, width)).astype(np.uint8)
+    if n is None:
+        n = rng.integers(0, width + 1, rows)
+    for r in range(rows):
+        moves[r, : n[r]] = rng.choice(ops, n[r])
+    return moves, n
+
+
+def _block_case(name, rng):
+    """(moves, n_moves, ops, rows) for one ``block_cigars`` case."""
+    if name == "random_ops":
+        return (*_random_block(rng), None, None)
+    if name == "ragged_rows":
+        n = np.array([0, 7, 33, 40, 2, 1, 19, 40, 11])  # empty, full, one
+        return (*_random_block(rng, n=n), None, None)
+    if name == "single_run_and_alternating":
+        moves, n = _random_block(rng, rows=4, width=12)
+        moves[0], n[0] = T.MOVE_UP, 12              # one run, all the row
+        moves[1, :12] = [T.MOVE_DIAG, T.MOVE_LEFT] * 6
+        moves[2, :12] = [T.MOVE_UP, T.MOVE_DIAG, T.MOVE_LEFT] * 4
+        n[1] = n[2] = 12
+        return moves, n, None, None
+    if name == "sam_ops":
+        return (*_random_block(rng),
+                {T.MOVE_DIAG: "M", T.MOVE_UP: "I", T.MOVE_LEFT: "D"}, None)
+    assert name == "pad_rows_cut"
+    moves, n = _random_block(rng)
+    moves[6:] = 0                                   # pad rows: stop code
+    return moves, n, None, 6
+
+
+@pytest.mark.parametrize("case", ["random_ops", "ragged_rows",
+                                  "single_run_and_alternating", "sam_ops",
+                                  "pad_rows_cut"])
+def test_block_cigars_match_row_by_row(rng, case):
+    moves, n, ops, rows = _block_case(case, rng)
+    got = block_cigars(moves, n, ops=ops, rows=rows)
+    keep = len(n) if rows is None else rows
+    assert got == [moves_to_cigar(moves[r], n[r], ops=ops)
+                   for r in range(keep)]
+    # independent of the encoder: each CIGAR spells its row's path
+    # start->end in maximal runs
+    letter = ops or {T.MOVE_DIAG: "M", T.MOVE_UP: "D", T.MOVE_LEFT: "I"}
+    for r, cigar in enumerate(got):
+        runs = re.findall(r"(\d+)([MID])", cigar)
+        assert "".join(k + o for k, o in runs) == cigar
+        assert "".join(int(k) * o for k, o in runs) == "".join(
+            letter[int(m)] for m in moves[r, : n[r]][::-1])
+        assert all(a[1] != b[1] for a, b in zip(runs, runs[1:]))
+
+
+def test_block_cigars_empty_and_all_zero_blocks():
+    moves = np.ones((3, 5), np.uint8)
+    assert block_cigars(moves, np.zeros(3, np.int32)) == ["", "", ""]
+    assert block_cigars(moves, np.array([2, 4, 1]), rows=0) == []
+    assert block_cigars(np.ones((1, 7), np.uint8), [7]) == ["7M"]
 
 
 def test_pack_lanes_roundtrip(rng):
@@ -119,7 +184,6 @@ def test_real_alignment_cigar_consumes_both_sequences(rng):
     r = jnp.asarray(rng.integers(0, 4, 33).astype(np.uint8))
     a = align(spec, params, q, r)
     cigar = moves_to_cigar(np.asarray(a.moves), int(a.n_moves))
-    import re
     q_span = sum(int(c) for c, o in re.findall(r"(\d+)([MDI])", cigar)
                  if o in "MD")
     r_span = sum(int(c) for c, o in re.findall(r"(\d+)([MDI])", cigar)
