@@ -56,7 +56,8 @@ import numpy as np  # noqa: E402
 from repro.core import alphabets, kernels_zoo, rescore  # noqa: E402
 from repro.core import types as T  # noqa: E402
 from repro.core.batch import make_sharded_aligner  # noqa: E402
-from repro.data.synthetic import sample_reads, sample_site  # noqa: E402
+from repro.data.synthetic import (junk_reads, sample_reads,  # noqa: E402
+                                  sample_site)
 from repro.prob import kernels as prob_kernels  # noqa: E402
 from repro.runtime import compile_cache, registry  # noqa: E402
 from repro.runtime import plan as plan_mod  # noqa: E402
@@ -304,8 +305,6 @@ def phase_pallas(rng, traffic, sz, on_tpu: bool) -> dict:
 
 
 def phase_map(rng, sz, seed: int) -> dict:
-    from benchmarks.bench_filter import junk_reads
-
     ref = alphabets.random_dna(rng, sz["ref_len"])
     reads = sample_reads(ref, sz["n_reads"], 150, error_rate=0.01,
                          seed=seed + 1)
@@ -318,7 +317,7 @@ def phase_map(rng, sz, seed: int) -> dict:
     svc.wait()
     counts = reconcile(svc.metrics(), len(reqs))
     n = sz["n_reads"]
-    # bench_mapping._accuracy: mapped within 5 bp of the true origin
+    # mapped within 5 bp of the true origin
     hits = sum(1 for i in range(n) if reqs[i].result["mapped"] and
                abs((reqs[i].result["pos"] - 1) - int(reads.pos[i])) <= 5)
     junk_rejected = sum(not r.result["mapped"] for r in reqs[n:])

@@ -14,8 +14,9 @@ fault-tolerance invariants:
 * the kill schedule fired and stranded batches were redispatched.
 
 Any violation is reported and the exit code is nonzero — this is the
-scriptable face of the ``bench_faults`` chaos gate, cheap enough for
-tier-1 (see scripts/tier1.sh) and sweepable over many seeds locally.
+scriptable face of the chaos contract ``tests/test_gateway.py`` holds,
+cheap enough for tier-1 (see scripts/tier1.sh) and sweepable over many
+seeds locally.
 
 Examples:
     python scripts/chaos.py                       # 3-seed default sweep

@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
-# Tier-1 verification: the full test suite, the quick benchmark gates,
-# and the plan linter.  One entry point for builders and CI; run from
+# Tier-1 verification: the full test suite, the plan linter and the
+# gateway chaos sweep.  One entry point for builders and CI; run from
 # the repo root.
 #
 #   scripts/tier1.sh            # everything (slow model/serve suites too)
@@ -17,13 +17,5 @@ if [[ "${1:-}" == "--quick" ]]; then
 fi
 
 python -m pytest -x -q "$@"
-python -m benchmarks.run --quick --only bucketing
-python -m benchmarks.run --quick --only mapping
-python -m benchmarks.run --quick --only serving
-python -m benchmarks.run --quick --only fill   # packed/strip parity gate
-python -m benchmarks.run --quick --only pairhmm  # forward-oracle parity gate
-python -m benchmarks.run --quick --only filter   # myers bit-exactness gate
-python -m benchmarks.run --quick --only autotune # table round-trip + parity gate
-python -m benchmarks.run --quick --only bench_obs # tracing overhead + reconcile gate
 python scripts/lint_plans.py                     # trace-time plan lint gate
 python scripts/chaos.py --seeds 0 --requests 32  # gateway fault-tolerance gate

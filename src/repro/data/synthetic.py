@@ -107,6 +107,20 @@ def sample_reads(ref, n: int, length: int, error_rate: float = 0.05,
                    strand=np.asarray(strand, bool))
 
 
+def junk_reads(rng, ref, n: int, read_len: int, plant_len: int = 20):
+    """Chimeric junk: random sequence with one planted exact reference
+    k-mer — it seeds and chains, then has no real placement (the read
+    class the mapper's filter ladder exists to reject)."""
+    out = []
+    for _ in range(n):
+        r = rng.integers(0, 4, read_len).astype(np.uint8)
+        p = int(rng.integers(0, len(ref) - plant_len))
+        o = int(rng.integers(0, read_len - plant_len))
+        r[o:o + plant_len] = ref[p:p + plant_len]
+        out.append(r)
+    return out
+
+
 @dataclasses.dataclass
 class GenotypingSite:
     """One simulated variant site with ground truth.

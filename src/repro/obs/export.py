@@ -8,8 +8,8 @@ its own named track, the supervising caller another — plus ``"C"``
 counter events (queue depth, pending units) that Perfetto renders as a
 counter track above the thread lanes.
 
-:func:`validate_chrome_trace` is the schema check the ``bench_obs``
-gate and ``scripts/obs_report.py`` run before trusting a trace: every
+:func:`validate_chrome_trace` is the schema check the tests and
+``scripts/obs_report.py`` run before trusting a trace: every
 event carries the required keys, complete events have non-negative
 microsecond durations, and track metadata is well-formed.  Validation
 failures are returned as strings (not raised) so callers can report all

@@ -6,8 +6,7 @@ of the same instrumentation.  The gateway feeds it per-channel queue
 depth, batch occupancy, padding waste, retry and dead-letter counts and
 submit→resolve latency; the plan cache feeds it hits, misses and
 compile seconds.  Two read-outs: a JSON-safe :meth:`~MetricsRegistry.
-snapshot` (what ``Gateway.metrics()`` and ``benchmarks/run.py --json``
-embed) and Prometheus text exposition
+snapshot` (what ``Gateway.metrics()`` embeds) and Prometheus text exposition
 (:meth:`~MetricsRegistry.prometheus`).
 
 Histograms are log-bucketed (two buckets per octave, so bucket edges

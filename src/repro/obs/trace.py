@@ -14,9 +14,7 @@ Design constraints, in order:
 * **Near-zero overhead when off.**  Tracing is disabled by default and
   gated by one process-global flag: the disabled ``span(...)`` call is a
   single branch returning a shared no-op context manager, and
-  ``@traced`` functions skip straight to the wrapped callable.  The
-  ``bench_obs`` overhead gate holds the disabled path to <1% of the
-  pipelined serving stream.
+  ``@traced`` functions skip straight to the wrapped callable.
 * **Thread-safe without a hot-path lock.**  Spans land in a *per-thread*
   ring buffer (``threading.local``) that only its owner writes; the
   global registry of rings is only locked at ring creation and at

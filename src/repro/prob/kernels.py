@@ -192,7 +192,7 @@ def pairhmm_backward(objective: str = "logsumexp", **kw) -> T.DPKernelSpec:
 # One spec object per configuration: the plan cache keys executables by
 # spec *identity-by-fields* (distinct constructions never share because
 # their PE closures differ), so everything dispatching the same kernel —
-# genotype.py, posterior.py, GenotypingService, the benchmarks — must
+# genotype.py, posterior.py, GenotypingService, the tests — must
 # resolve its spec through these.
 @functools.lru_cache(maxsize=None)
 def cached_pairhmm(objective: str = "logsumexp", band=None) -> T.DPKernelSpec:

@@ -2,10 +2,10 @@
 compiled-plan cache, length-bucketed batching).
 
 This is the fixed back-end of DP-HLS §5 recast as a software layer: every
-caller (core.api, core.batch, core.tiling, serve, benchmarks) resolves its
-engine through one registry, compiles through one plan cache, and pads
-through one bucketing policy — instead of five independent jit call sites
-and a global max_len pad.
+caller (core.api, core.batch, core.tiling, serve, the chip benchmark)
+resolves its engine through one registry, compiles through one plan
+cache, and pads through one bucketing policy — instead of five
+independent jit call sites and a global max_len pad.
 """
 from .registry import (Engine, available_engines, engine_options,
                        engine_tunable, get_engine, register_engine)

@@ -1,6 +1,6 @@
 """Where entry points keep JAX's persistent compilation cache.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``, ``scripts/*.py``)
+Entry points (``chip_smoke.py``, ``bench/harness.py``, ``scripts/*.py``)
 call :func:`enable` once at start-up; library code and tests never do.
 If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses that directory
 and nothing else is set.  Otherwise the cache goes to ``.jax_cache/`` at

@@ -3,9 +3,9 @@
 The paper synthesizes one fixed back-end per kernel configuration and
 reuses it for every block/channel; the JAX analogue is one jitted
 ``fill (+ traceback)`` executable per ``(kernel, engine, bucket_shape,
-batch_size, with_traceback)`` — memoized here so api/batch/serve/tiling/
-benchmarks share a single cache instead of five independent ``jax.jit``
-call sites, each re-tracing the same schedule.
+batch_size, with_traceback)`` — memoized here so api/batch/serve/tiling
+and the chip benchmark share a single cache instead of five independent
+``jax.jit`` call sites, each re-tracing the same schedule.
 """
 from __future__ import annotations
 

@@ -18,10 +18,9 @@ backend — the sweep:
    the measured set, so the winner matches-or-beats the hand-picked
    schedule on the very run that recorded it.
 
-Timing uses the same stream discipline as ``benchmarks/bench_fill``:
-request lengths drawn from ``(bucket/2, bucket]`` — the distribution
-power-of-two bucketing guarantees — so early-exit savings are measured
-at serving-realistic, not best-case, lengths.
+Timing draws request lengths from ``(bucket/2, bucket]`` — the
+distribution power-of-two bucketing guarantees — so early-exit savings
+are measured at serving-realistic, not best-case, lengths.
 """
 from __future__ import annotations
 

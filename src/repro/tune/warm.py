@@ -12,9 +12,8 @@ cache entry.
 
 Cold-vs-warm is measurable, not anecdotal: every ``CompiledPlan`` stamps
 its first-dispatch ``compile_s``, and ``plan_cache_info()['totals']
-['compile_s']`` sums it across live + retired plans — the number
-``benchmarks/bench_autotune`` reports as time-to-first-result moved from
-request latency to boot.
+['compile_s']`` sums it across live + retired plans — time-to-first-result
+moved from request latency to boot.
 """
 from __future__ import annotations
 

@@ -29,3 +29,20 @@ def make_kernel_inputs(rng, spec, nq: int, nr: int):
                 jnp.asarray(rng.integers(0, 20, nr).astype(np.uint8)))
     return (jnp.asarray(rng.integers(0, 4, nq).astype(np.uint8)),
             jnp.asarray(rng.integers(0, 4, nr).astype(np.uint8)))
+
+
+def seed_plan(spec, engine: str, bucket: int):
+    """The seed executable the plan cache's defaults must match bit for
+    bit: vmapped fill plus per-row while-loop traceback at strip=1,
+    tb_pack=1, the fill walking every diagonal of the bucket (no early
+    exit)."""
+    import functools
+    import jax
+    from repro.runtime import plan as plan_mod, registry
+    supported = registry.engine_options(engine)
+    opts = {k: 1 for k in ("strip", "tb_pack") if k in supported}
+    if supported.get("live_bound") == "dynamic":
+        opts["live_bound"] = 2 * bucket
+    engine_fn = functools.partial(registry.get_engine(engine), **opts)
+    single = functools.partial(plan_mod.align_impl, spec, engine_fn)
+    return jax.jit(jax.vmap(single, in_axes=(None, 0, 0, 0, 0)))

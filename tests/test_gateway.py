@@ -271,6 +271,60 @@ def test_serve_elastic_respawns_killed_worker(rng):
     assert svc._pending == 0 and svc.inflight == {}
 
 
+_CHAOS = {
+    # two of four workers die mid-stream; the heartbeat deadline
+    # reclaims the batches they stranded
+    "kill_2_of_4": dict(fault_plan=FaultPlan(seed=0, kill={"w0": 1, "w1": 1}),
+                        redispatch_after=0.75, max_retries=4),
+    # seeded launch and harvest failures plus harvest latency
+    "flaky_fabric": dict(fault_plan=FaultPlan(seed=7, fail_launch_p=0.12,
+                                              fail_harvest_p=0.08,
+                                              latency_s=0.02, latency_p=0.2),
+                         max_retries=8),
+}
+
+
+@pytest.mark.parametrize("scenario", sorted(_CHAOS))
+def test_chaos_stream_matches_fault_free_run(scenario):
+    """A 64-request mixed-length stream served by four workers under
+    faults: every request resolves, none is dead-lettered or completed
+    twice, and each result is bit-identical to a fault-free inline drain.
+    Coalescing is off, so a request's padded shape does not depend on
+    queue state."""
+    rng = np.random.default_rng(0)
+    lens = np.minimum(128, 24 + rng.exponential(104 / 3, (64, 2)).astype(int))
+    base = [AlignRequest(rid=i, kernel="global_affine",
+                         query=rng.integers(0, 4, lq).astype(np.uint8),
+                         ref=rng.integers(0, 4, lr).astype(np.uint8))
+            for i, (lq, lr) in enumerate(lens)]
+
+    def run(**kw):
+        reqs = [AlignRequest(rid=r.rid, kernel=r.kernel, query=r.query,
+                             ref=r.ref) for r in base]
+        svc = AlignmentService(max_len=128, block=2, coalesce=False,
+                               pipeline_depth=2, **kw)
+        svc.submit_all(reqs)
+        return svc, reqs
+
+    oracle, want = run()
+    oracle.drain()                 # also compiles every bucket's plan
+    svc, reqs = run(**_CHAOS[scenario])
+    stats = svc.serve(n_workers=4, timeout_s=120.0)
+    assert all(r.result is not None for r in reqs)
+    assert not any(r.result.get("failed") for r in reqs)
+    assert [r.result for r in reqs] == [r.result for r in want]
+    assert stats["completed"] == len(reqs)
+    metrics = svc.metrics()
+    assert metrics["reconcile"]["ok"]
+    assert metrics["metrics"]["counters"].get("gw_retries_total", 0) == \
+        stats["retries"]
+    if scenario == "kill_2_of_4":
+        assert sorted(k["worker"] for k in stats["killed"]) == ["w0", "w1"]
+        assert stats["redispatched"] >= 1
+    else:
+        assert stats["faults"] >= 1 and stats["retries"] >= 1
+
+
 # -- launch order: fullest block first ---------------------------------------
 def _sized(rid, rng, n):
     return AlignRequest(rid=rid, kernel="global_affine",

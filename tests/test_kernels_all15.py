@@ -13,7 +13,7 @@ import pytest
 from repro.core import align, kernels_zoo, rescore
 from repro.core import types as T
 
-from conftest import make_kernel_inputs
+from conftest import make_kernel_inputs, seed_plan
 
 ALL_KERNELS = list(range(1, 16))
 
@@ -70,10 +70,8 @@ def test_packed_strip_fill_bit_identical_to_seed(kid, rng):
     early-exit fill, batched traceback walk — must produce bit-identical
     (score, start, end, moves) vs the seed schedule (strip=1, one byte
     per pointer, full-bucket fill) for every zoo kernel."""
-    import functools
-    import jax
     import jax.numpy as jnp
-    from repro.runtime import plan as plan_mod, registry
+    from repro.runtime import plan as plan_mod
 
     spec, params = kernels_zoo.make(kid)
     B, bucket = 5, 64
@@ -86,13 +84,7 @@ def test_packed_strip_fill_bit_identical_to_seed(kid, rng):
     if spec.band is not None:
         rl = ql                      # keep the corner inside the band
 
-    # the seed executable: unpacked, one diagonal per step, no early
-    # exit, per-row while-loop traceback under vmap
-    engine_fn = functools.partial(registry.get_engine("wavefront"),
-                                  strip=1, tb_pack=1, live_bound=2 * bucket)
-    seed = jax.jit(jax.vmap(
-        functools.partial(plan_mod.align_impl, spec, engine_fn),
-        in_axes=(None, 0, 0, 0, 0)))
+    seed = seed_plan(spec, "wavefront", bucket)
     char = spec.char_shape
     opt = plan_mod.get_plan(spec, "wavefront", (bucket,) + char,
                             (bucket,) + char, batch_size=B)
@@ -163,6 +155,35 @@ def test_global_symmetry(rng):
     s1 = align(spec, params, q, r, with_traceback=False).score
     s2 = align(spec, params, r, q, with_traceback=False).score
     assert int(s1) == int(s2)
+
+
+def _nw_loop(q, r, match, mismatch, gap):
+    """Textbook row-major Needleman-Wunsch with a linear gap, score only."""
+    prev = gap * np.arange(len(r) + 1)
+    for i in range(1, len(q) + 1):
+        cur = np.empty_like(prev)
+        cur[0] = gap * i
+        for j in range(1, len(r) + 1):
+            sub = match if q[i - 1] == r[j - 1] else mismatch
+            cur[j] = max(prev[j - 1] + sub, prev[j] + gap, cur[j - 1] + gap)
+        prev = cur
+    return int(prev[-1])
+
+
+def test_batched_nw_matches_textbook_loop(rng):
+    """The spec-driven batched engine's global_linear scores equal an
+    independent row-major loop that knows nothing of the DP spec."""
+    from repro.core import batch as core_batch
+    spec, params = kernels_zoo.make(1)
+    qs = rng.integers(0, 4, (4, 96)).astype(np.uint8)
+    rs = rng.integers(0, 4, (4, 96)).astype(np.uint8)
+    n = np.full(4, 96, np.int32)
+    got = core_batch.align_batch(spec, params, qs, rs, n, n,
+                                 with_traceback=False).score
+    sc = {k: int(v) for k, v in params.items()}
+    want = [_nw_loop(q, r, sc["match"], sc["mismatch"], sc["gap"])
+            for q, r in zip(qs, rs)]
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_identity_alignment_scores_perfect(rng):

@@ -6,11 +6,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import alphabets
-from repro.data.synthetic import sample_reads
+from repro.data.synthetic import junk_reads, sample_reads
 from repro.mapping import (FLAG_REVERSE, ReadMapper, build_index,
                            chain_anchors, cigar_spans, kmer_hashes,
                            minimizers, seed_anchors, top_anchors)
 from repro.mapping import index as index_mod
+from repro.obs import trace as obs_trace
 from repro.runtime import plan as plan_mod
 
 
@@ -248,6 +249,44 @@ def test_mapper_random_read_is_unmapped(rng):
     (rec,) = mapper.map_reads([alien])
     assert not rec.is_mapped
     assert rec.pos == 0 and rec.mapq == 0 and rec.cigar == ""
+
+
+def test_junk_reads_carry_one_planted_reference_kmer(rng):
+    ref = alphabets.random_dna(rng, 4096)
+    kmers = {ref[p:p + 20].tobytes() for p in range(len(ref) - 19)}
+    reads = junk_reads(rng, ref, 16, 150)
+    assert len(reads) == 16
+    for read in reads:
+        assert read.dtype == np.uint8 and len(read) == 150
+        assert any(read[o:o + 20].tobytes() in kmers for o in range(131))
+
+
+def test_filter_ladder_rejects_chimeric_junk_without_costing_accuracy(rng):
+    """Chimeric junk seeds and chains on its planted 20-mer, then has no
+    placement: the myers screen stops every junk read before extension,
+    and genuine reads map at least as well as with the screen off."""
+    ref = alphabets.random_dna(rng, 8192)
+    rs = sample_reads(ref, 20, 150, error_rate=0.05, seed=1)
+    reads = [rs.reads[i, :rs.lens[i]] for i in range(20)]
+    reads += junk_reads(rng, ref, 20, 150)
+    accuracy, extended = {}, {}
+    obs_trace.enable()
+    try:
+        for mode in ("myers", "off"):
+            obs_trace.clear()
+            recs = ReadMapper(ref, filter_mode=mode).map_reads(reads)
+            (span,) = [s for s in obs_trace.spans() if s.name == "map.extend"]
+            extended[mode] = span.args["n"]
+            accuracy[mode] = sum(
+                recs[i].is_mapped and
+                abs((recs[i].pos - 1) - int(rs.pos[i])) <= 5
+                for i in range(20)) / 20
+            assert not any(r.is_mapped for r in recs[20:])
+    finally:
+        obs_trace.disable()
+        obs_trace.clear()
+    assert extended == {"myers": 20, "off": 40}
+    assert accuracy["myers"] >= max(accuracy["off"], 0.95)
 
 
 def test_extension_reuses_plan_cache_across_calls(rng):

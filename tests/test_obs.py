@@ -1,12 +1,12 @@
 """Observability layer: span tracer, metrics registry, trace export,
 and the gateway/plan-cache integration.
 
-The contracts under test are the ones ``bench_obs`` gates dynamically:
-the disabled path emits nothing, concurrent writers never lose or
-corrupt each other's spans, rings wrap oldest-first, exported traces
-validate against the Chrome trace-event schema, and the gateway's
-metrics snapshot reconciles exactly with its futures — clean runs and
-faulty ones alike.
+The contracts under test: the disabled path emits nothing, concurrent
+writers never lose or corrupt each other's spans, rings wrap
+oldest-first, tracing never changes a result, exported traces validate
+against the Chrome trace-event schema with a named track per worker,
+and the gateway's metrics snapshot reconciles exactly with its futures
+— clean runs and faulty ones alike.
 """
 from __future__ import annotations
 
@@ -538,6 +538,31 @@ def test_host_seconds_are_counted_with_tracing_off(rng):
     counters = svc.metrics()["metrics"]["counters"]
     assert counters["gw_host_seconds_total{phase=pad}"] > 0
     assert counters["gw_host_seconds_total{phase=land}"] > 0
+
+
+def test_traced_serve_keeps_results_and_names_each_worker_track(rng):
+    """Tracing only observes: a two-worker ``serve()`` gives the same
+    results with it on as with it off, and its export validates with a
+    named track for every worker that launched a batch."""
+    base = _short_reads(rng, 24)
+    results = {}
+    for traced in (False, True):
+        if traced:
+            trace.enable()
+        reqs = [AlignRequest(rid=r.rid, kernel=r.kernel, query=r.query,
+                             ref=r.ref) for r in base]
+        svc = AlignmentService(max_len=32, block=4, pipeline_depth=2)
+        svc.submit_all(reqs)
+        svc.serve(n_workers=2, timeout_s=120.0)
+        results[traced] = [r.result for r in reqs]
+    obj = obs_export.to_chrome_trace()
+    assert obs_export.validate_chrome_trace(obj) == []
+    assert results[True] == results[False]
+    workers = {s.args["worker"] for s in trace.spans()
+               if s.name == "gw.launch"}
+    tracks = {e["args"]["name"] for e in obj["traceEvents"]
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert workers and {f"gw-{w}" for w in workers} <= tracks
 
 
 def test_dump_trace_writes_valid_file(tmp_path, rng):

@@ -13,6 +13,8 @@ from repro.runtime import (available_engines, bucket_length, bucket_shape,
                            pad_to_bucket, register_engine)
 from repro.runtime import plan as plan_mod
 
+from conftest import seed_plan
+
 
 # ---------------------------------------------------------------------------
 # registry
@@ -326,6 +328,36 @@ def test_traceback_bytes_estimator():
     assert plan_mod.traceback_bytes(spec_v, 256, 256) == 0
 
 
+@pytest.mark.parametrize("batch", [1, 8])
+@pytest.mark.parametrize("bucket", [64, 128, 256, 512])
+@pytest.mark.parametrize("engine", ["wavefront", "pallas_interpret"])
+def test_plan_defaults_match_seed_schedule(engine, bucket, batch):
+    """The plan cache's defaults (backend strip, packed pointers, the
+    fill's early exit at the block's length bound, the batched
+    traceback) give bit-identical alignments to the seed schedule, over
+    two length-sorted blocks of lengths in (bucket/2, bucket] — the
+    blocks the service's bucket queues form, longest first."""
+    rng = np.random.default_rng(bucket + batch)
+    spec, params = kernels_zoo.make("global_affine")
+    total = 2 * batch
+    qs = rng.integers(0, 4, (total, bucket)).astype(np.uint8)
+    rs = rng.integers(0, 4, (total, bucket)).astype(np.uint8)
+    ql = rng.integers(bucket // 2 + 1, bucket + 1, total).astype(np.int32)
+    rl = rng.integers(bucket // 2 + 1, bucket + 1, total).astype(np.int32)
+    order = np.argsort(-(ql.astype(np.int64) + rl), kind="stable")
+    seed = seed_plan(spec, engine, bucket)
+    plan = plan_mod.get_plan(spec, engine, (bucket,), (bucket,),
+                             batch_size=batch)
+    for sel in np.split(order, 2):
+        block = (qs[sel], rs[sel], ql[sel], rl[sel])
+        a, b = seed(params, *block), plan(params, *block)
+        for f in ("score", "end_i", "end_j", "start_i", "start_j",
+                  "n_moves", "moves"):
+            np.testing.assert_array_equal(np.asarray(getattr(a, f)),
+                                          np.asarray(getattr(b, f)),
+                                          err_msg=f)
+
+
 # ---------------------------------------------------------------------------
 # service: per-(kernel, bucket) padding instead of one global max_len
 # ---------------------------------------------------------------------------
@@ -433,6 +465,23 @@ def test_service_budget_sized_blocks(rng):
         direct = align(spec, params, jnp.asarray(req.query),
                        jnp.asarray(req.ref), with_traceback=False)
         assert req.result["score"] == pytest.approx(float(direct.score))
+
+
+@pytest.mark.parametrize("kernel,ratio", [("global_linear", 4),
+                                          ("global_affine", 2)])
+def test_service_budget_rows_grow_with_packing(kernel, ratio):
+    """At a fixed traceback budget and a 4 kb bucket, packed pointers let
+    one launch carry at least ``ratio`` times the rows the unpacked seed
+    store admits: 4x for 2-bit linear pointers, 2x for 4-bit affine."""
+    from repro.serve import AlignmentService
+    budget, bucket = 256 << 20, 4096
+    spec, _ = kernels_zoo.make(kernel)
+    seed_rows = budget // plan_mod.traceback_bytes(spec, bucket, bucket,
+                                                   strip=1, tb_pack=1)
+    svc = AlignmentService(max_len=bucket, block=1, tb_budget_bytes=budget,
+                           max_block=1 << 16)
+    assert seed_rows >= 1
+    assert svc.block_for(kernel, (bucket, bucket)) >= ratio * seed_rows
 
 
 def test_resolve_engine_opts_shim_warns_and_matches():
